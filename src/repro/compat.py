@@ -1,33 +1,22 @@
-"""JAX version-portability layer.
+"""Single import point for the JAX symbols the codebase re-exports.
 
-The repo targets both JAX 0.4.x and >= 0.5 APIs. The moved/renamed symbols
-used by the codebase are resolved here, once, so call sites never touch
-`jax.experimental` or version-sniff on their own:
+The repo targets one installed JAX (see requirements-ci.txt). Call sites
+import these names from here rather than from `jax` directly, so a future
+rename is a one-line fix in this file:
 
-  * `shard_map`  — top-level `jax.shard_map` (>= 0.4.35 on some builds /
-    >= 0.5) with fallback to `jax.experimental.shard_map.shard_map`.
-  * `make_mesh`  — top-level `jax.make_mesh` (>= 0.4.35) with fallback to
-    building a `Mesh` from `mesh_utils.create_device_mesh`.
+  * `shard_map`  — `jax.shard_map`.
+  * `make_mesh`  — `jax.make_mesh`.
   * `tree_map` / `tree_leaves` / `tree_flatten` / `tree_unflatten` /
-    `tree_structure` — the `jax.tree_util` spellings (stable across both
-    lines; re-exported so future renames are one-line fixes here).
-  * `threefry2x32` — the raw Threefry-2x32 hash primitive (private
-    `jax._src.prng` location), used by the Monte Carlo engine to draw
-    node-count-dependent random vectors with static shapes (counts as
-    data). `None` when the internals moved; callers must fall back to
-    shaped draws. `threefry_is_default()` reports whether `jax.random.key`
-    produces threefry keys (the bit-level replication is only valid then).
+    `tree_structure` — the `jax.tree_util` spellings.
 
 Policy (see docs/montecarlo.md): production modules and tests import these
-from `repro.compat`; only this file may probe `jax.experimental`,
-`jax._src`, or the JAX version string.
+from `repro.compat`; no module probes the JAX version.
 """
 from __future__ import annotations
 
 import jax
 
 __all__ = [
-    "JAX_VERSION",
     "shard_map",
     "make_mesh",
     "tree_map",
@@ -35,94 +24,11 @@ __all__ = [
     "tree_flatten",
     "tree_unflatten",
     "tree_structure",
-    "threefry2x32",
-    "threefry_is_default",
-    "threefry_split_is_original",
 ]
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-)
+shard_map = jax.shard_map
+make_mesh = jax.make_mesh
 
-# ---- shard_map -----------------------------------------------------------
-if hasattr(jax, "shard_map"):  # JAX >= 0.5 (also late 0.4.x nightlies)
-    shard_map = jax.shard_map
-else:  # JAX 0.4.x: the experimental location
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# ---- make_mesh -----------------------------------------------------------
-if hasattr(jax, "make_mesh"):
-    make_mesh = jax.make_mesh
-else:  # pre-0.4.35
-
-    def make_mesh(axis_shapes, axis_names, *, devices=None):
-        from jax.experimental import mesh_utils
-        from jax.sharding import Mesh
-
-        if devices is None:
-            devices = jax.devices()
-        return Mesh(
-            mesh_utils.create_device_mesh(axis_shapes, devices=list(devices)),
-            axis_names,
-        )
-
-
-# ---- threefry primitive --------------------------------------------------
-try:
-    from jax._src.prng import threefry2x32_p as _threefry2x32_p
-
-    def threefry2x32(k1, k2, x0, x1):
-        """Raw Threefry-2x32 hash: two uint32 key words, two equal-length
-        uint32 count vectors -> the two hashed output vectors."""
-        return _threefry2x32_p.bind(k1, k2, x0, x1)
-
-except Exception:  # pragma: no cover - future JAX moved the primitive
-    threefry2x32 = None
-
-
-def threefry_is_default() -> bool:
-    """Whether `jax.random.key` uses the threefry2x32 impl (the default
-    unless `jax_default_prng_impl` was overridden). Evaluated fresh each
-    call — it guards trace-time decisions and the config can change
-    between traces."""
-    return "fry" in str(jax.random.key(0).dtype)
-
-
-import functools as _functools
-
-
-@_functools.lru_cache(maxsize=None)
-def _split_layout_is_original(_partitionable: bool, _impl: str) -> bool:
-    # cache key = the two config knobs that can change the split layout at
-    # runtime; `ensure_compile_time_eval` keeps the probe concrete even
-    # when the first call happens inside a jit/scan trace
-    import jax.numpy as jnp
-
-    with jax.ensure_compile_time_eval():
-        key = jax.random.key(0)
-        kd = jax.random.key_data(key)
-        ref = jax.random.key_data(jax.random.split(key, 3)).ravel()
-        x0 = jnp.arange(3, dtype=jnp.uint32)
-        o0, o1 = threefry2x32(kd[0], kd[1], x0, x0 + jnp.uint32(3))
-        return bool(jnp.all(jnp.concatenate([o0, o1]) == ref))
-
-
-def threefry_split_is_original() -> bool:
-    """Whether `jax.random.split` produces the ORIGINAL threefry layout:
-    `threefry2x32(key, iota(2*num))` in `random_bits` counter order,
-    reshaped to `(num, 2)`. The Monte Carlo engine's counts-as-data key
-    splitting (per-row antenna counts with static shapes) replicates that
-    layout; `jax_threefry_partitionable` (default on newer JAX) changes it,
-    so the layout is *verified empirically* — one tiny concrete split,
-    cached per PRNG-config state — rather than version-sniffed. Callers
-    fall back to a `lax.switch` over per-count splits when False."""
-    if threefry2x32 is None or not threefry_is_default():
-        return False
-    part = bool(getattr(jax.config, "jax_threefry_partitionable", False))
-    return _split_layout_is_original(part, str(jax.random.key(0).dtype))
-
-
-# ---- tree utils ----------------------------------------------------------
 tree_map = jax.tree_util.tree_map
 tree_leaves = jax.tree_util.tree_leaves
 tree_flatten = jax.tree_util.tree_flatten

@@ -187,8 +187,7 @@ def shard_map_aggregate(
     gcfg: GBMAConfig,
     axis_names: Sequence[str] = ("data",),
 ) -> PyTree:
-    """Explicit OTA protocol body — call inside `repro.compat.shard_map`
-    (the version-portable spelling; `jax.shard_map` does not exist on 0.4.x).
+    """Explicit OTA protocol body — call inside `repro.compat.shard_map`.
 
     Each device scales its local gradient by its own slot gain (the analog
     amplification sqrt(E_N) h g after phase correction and matched filtering),

@@ -5,8 +5,8 @@ Layers (see each module's docstring):
   * `problems` — problem containers + the open `PROBLEMS` registry
     (`register_problem`); built-ins `quadratic`, `localization`, and the
     stochastic-capable `logistic`.
-  * `sampling` — reference-twin RNG samplers (padded / dynamic-count
-    threefry draws, antenna key replay).
+  * `sampling` — reference-twin RNG samplers (padded sweeps draw at
+    N_max / M_max and mask: threefry draws are prefix-stable).
   * `slots`    — per-slot algorithm updates behind `register_algo`
     (`ALGOS` derives from the registry) + each algorithm's `hoist_draws`
     RNG-plan twin.
@@ -65,6 +65,7 @@ from repro.core.mc.slots import (
     AlgoSpec,
     SlotCtx,
     register_algo,
+    resolve_ota_impl,
 )
 
 
@@ -104,6 +105,7 @@ __all__ = [
     "quadratic_mc_problem",
     "register_algo",
     "register_problem",
+    "resolve_ota_impl",
     "run_mc",
     "slice_result",
     "static_signature",

@@ -27,9 +27,10 @@ statically disables sampling and is bit-identical to running the same
 problem registered without a stochastic gradient.
 
 `run_mc(ota_impl=)` routes the single-antenna OTA superposition through
-`repro.kernels.ota.ota_edge_aggregate` ('pallas' on TPU / 'ref' jnp
-oracle); 'auto' picks pallas on TPU when eligible and the inline einsum
-otherwise.
+`repro.kernels.ota.ota_edge_aggregate` ('pallas' on TPU, 'interpret' in
+the Pallas interpreter, 'ref' jnp oracle) or the inline einsum
+('inline'); 'auto' picks pallas on TPU when eligible and the inline
+einsum otherwise (`slots.resolve_ota_impl`).
 """
 from __future__ import annotations
 
@@ -55,7 +56,7 @@ from repro.core.mc.plan import (
     validate_plan,
 )
 from repro.core.mc.problems import MCProblem, MCProblemBatch, PROBLEMS
-from repro.core.mc.slots import ALGO_REGISTRY
+from repro.core.mc.slots import ALGO_REGISTRY, resolve_ota_impl
 from repro.core.theory import ProblemConstants, theorem1_bound
 
 Array = jax.Array
@@ -142,29 +143,6 @@ def _resolve_n_shards(n_seeds: int, shard_seeds: Optional[bool]) -> int:
             f"shard_seeds=True needs seeds ({n_seeds}) divisible by the "
             f"device count ({ndev})")
     return ndev
-
-
-def _resolve_ota_impl(ota_impl: str, n_sizes: tuple) -> str:
-    """'auto' → 'pallas' on TPU when the kernel applies, 'inline' else.
-
-    The OTA kernel normalizes by a STATIC node count, so it only applies
-    when every row transmits at the same (full, unpadded) N — explicit
-    'pallas'/'ref' on a padded node-count sweep is an error rather than a
-    silent wrong normalization.
-    """
-    if ota_impl not in ("auto", "pallas", "ref"):
-        raise ValueError(
-            f"ota_impl must be 'auto', 'pallas' or 'ref', got {ota_impl!r}")
-    eligible = len(n_sizes) == 1
-    if ota_impl == "auto":
-        return "pallas" if (eligible and jax.default_backend() == "tpu") \
-            else "inline"
-    if not eligible:
-        raise ValueError(
-            f"ota_impl={ota_impl!r} needs a single node count per call "
-            f"(got n_sizes={n_sizes}): the OTA kernel normalizes by the "
-            "static N, which a padded node-count sweep does not have")
-    return ota_impl
 
 
 def _resolve_batch_frac(batch_frac, n_rows: int, batch_prob, problem):
@@ -264,7 +242,8 @@ def run_mc(
     per-row fraction sweep is one compile.
 
     `ota_impl`: 'auto' (inline einsum; pallas kernel on TPU when the node
-    count is static), 'pallas' or 'ref' force the
+    count is static), 'inline' forces the einsum, 'pallas' (TPU only),
+    'interpret' (the kernel in the Pallas interpreter) or 'ref' force the
     `repro.kernels.ota.ota_edge_aggregate` path for the single-antenna OTA
     superposition.
 
@@ -274,8 +253,8 @@ def run_mc(
     `plan=` an `ExecPlan` pins every field (rng_plan, seed_chunk,
     n_shards, row_shards, keep_seed_curves, ota_impl); `plan="auto"`
     derives one from the analytic memory model, the per-device memory
-    budget (`memory_budget_bytes=`, default: backend-reported limit or
-    2 GiB) and the visible device topology via `auto_plan`; or leave
+    budget (`memory_budget_bytes=`, default: the accelerator's reported
+    limit, or 2 GiB on the CPU) and the visible device topology via `auto_plan`; or leave
     `plan=None` and set the legacy knobs below — they build the
     equivalent plan (behavior-pinned), and mixing them with `plan=` is
     an error. The resolved plan is recorded on `MCResult.plan`.
@@ -467,7 +446,7 @@ def run_mc(
             "seed_chunk set and keep_seed_curves=False (only the chunk "
             "cursor and moment accumulators are checkpointed)")
 
-    ota_resolved = _resolve_ota_impl(eff_plan.ota_impl, n_sizes)
+    ota_resolved = resolve_ota_impl(eff_plan.ota_impl, n_sizes)
     # static promise for the hoisted plan's phase-stream shortcut: every
     # row's phase draw is over [-0, 0] (cos(0)=1, value-identical to
     # skip). Only hoist-eligible calls (hoisted plan, one algorithm WITH
@@ -481,8 +460,6 @@ def run_mc(
                 for c in ch_batch.configs))
     params = dict(ch_batch.params)
     params["n_nodes"] = jnp.asarray(n_nodes, jnp.float32)
-    params["n_idx"] = jnp.asarray(
-        [n_sizes.index(n) for n in n_nodes], jnp.int32)
     params["algo_idx"] = jnp.asarray(
         [algo_set.index(a) for a in algos], jnp.int32)
     params["gamma"] = jnp.asarray(
@@ -503,8 +480,6 @@ def run_mc(
     params["tx_budget"] = jnp.asarray(budgets, jnp.float32)
     if m_sizes:
         params["n_antennas"] = jnp.asarray(m_per_row, jnp.float32)
-        params["m_idx"] = jnp.asarray(
-            [m_sizes.index(m) for m in m_per_row], jnp.int32)
     if b_counts is not None:
         # int32, NOT float32: a lane count is integral and must survive
         # exactly (float32 rounds above 2^24); the single consumer divides

@@ -135,6 +135,23 @@ _STATIC_ARGNAMES = (
 )
 
 
+def _f32_matmuls(fn):
+    """Trace `fn` with every f32 matmul at full f32 precision. The engine
+    simulates f32 arithmetic that its references (the CPU reference
+    simulators, the Theorem-1 bound) assume; the TPU's default precision
+    multiplies f32 matrices in bf16 (3e-3 relative error on the vmapped
+    (32, 90) x (90, 500) gradient product on a TPU v5e), which moved the
+    Fig. 3 mean curves 4.9e-4 relative from the CPU run (5.0e-6 with this
+    setting). The setting is read at trace time, so it rides inside the
+    jitted program and leaves the caller's own default untouched."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
+@_f32_matmuls
 def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
                   row_based, algo_set, fading, steps, n_sizes, n_antennas,
                   m_sizes, invert_channel, h_min, n_shards, row_shards=1,
@@ -175,8 +192,8 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
     'hoisted' feeds the algorithm's pre-materialized draw streams to the
     scan as inputs — homogeneous (single-algorithm) calls only, since a
     mixed batch would materialize every algorithm's streams per
-    trajectory; mixed calls and 'inscan' run the legacy body (including
-    PR 2's N-sweep-only gain hoisting), kept as the benchmark baseline.
+    trajectory; mixed calls and 'inscan' run the legacy in-scan body,
+    kept as the benchmark baseline.
 
     `reduce_moments` (python-level, not a jit argname: the jitted
     wrappers pin it at their call sites) switches the return value from
@@ -189,10 +206,6 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
     global _TRACE_COUNT
     _TRACE_COUNT += 1  # python side effect: runs once per trace/compile
 
-    # gains-consuming slot types, single-antenna: eligible for the legacy
-    # (inscan-plan) hoisting of the per-N sampling switch out of the scan
-    hoistable = n_antennas is None and not m_sizes and any(
-        ALGO_REGISTRY[a].hoist_gains(invert_channel) for a in algo_set)
     use_ec = any(ALGO_REGISTRY[a].error_feedback for a in algo_set)
     # The hoisted plan applies to HOMOGENEOUS calls only: with several
     # algorithms dispatched per row by the traced algo_idx switch, every
@@ -212,17 +225,16 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
         n_max_ = row["mask"].shape[0]
         dim = t0.shape[0]
 
-        def make_ctx(h_slot, draws=None):
+        def make_ctx(draws=None):
             return SlotCtx(fading=fading, p=p, mask=row["mask"],
                            n_sizes=n_sizes, n_antennas=n_antennas,
                            m_sizes=m_sizes, invert_channel=invert_channel,
-                           h_min=h_min, h_slot=h_slot, ota_impl=ota_impl,
+                           h_min=h_min, ota_impl=ota_impl,
                            phase_zero=phase_zero, draws=draws)
 
-        def slot(g, k, h_slot, dr_all):
+        def slot(g, k, dr_all):
             def ctx_for(a):
-                dr = dr_all.get(a) if dr_all is not None else None
-                return make_ctx(h_slot, dr)
+                return make_ctx(dr_all.get(a) if dr_all is not None else None)
 
             if len(algo_set) == 1:
                 return ALGO_REGISTRY[algo_set[0]].slot_fn(
@@ -234,7 +246,7 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
             return jax.lax.switch(p["algo_idx"], branches, k)
 
         def body(carry, x):
-            k, h_slot, dk, dr_all, idx, pu = x
+            k, dk, dr_all, idx, pu = x
             if use_ec:
                 theta, m, e_res, cum_e = carry
             else:
@@ -274,7 +286,7 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
                 e_res = p["ec"] * (u - x_tx)
             cum_e = cum_e + p["energy"] * jnp.sum(
                 x_tx.astype(jnp.float32) ** 2)
-            v = slot(x_tx, k, h_slot, dr_all)
+            v = slot(x_tx, k, dr_all)
             m = p["gamma"] * m + v
             theta = theta - beta * m
             carry = (theta, m, e_res, cum_e) if use_ec \
@@ -286,7 +298,6 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
         if sgrad_fn is not None:
             data_keys = jax.random.split(
                 jax.random.fold_in(key, _DATA_STREAM), steps)
-        h_all = None
         draws_all = None
         idx_all = None
         if hoist:
@@ -294,7 +305,7 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
             # as one batched (steps, ...) draw outside the scan, via each
             # algorithm's hoist_draws twin. Streams replay the in-scan
             # key-split chains exactly, so the plans are interchangeable.
-            ctx0 = make_ctx(None)
+            ctx0 = make_ctx()
             draws_all = {}
             for a in algo_set:
                 hd = ALGO_REGISTRY[a].hoist_draws
@@ -303,34 +314,10 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
             if not draws_all:
                 # algorithm registered without a hoist twin: nothing was
                 # hoisted — fall through to the legacy in-scan body
-                # (including its N-sweep gain hoist below) instead of
-                # running a strictly worse plan
                 draws_all = None
             if hoist_idx:
                 idx_all = jax.vmap(
                     lambda dk: sample_idx_fn(row, dk, b_max))(data_keys)
-        if draws_all is None and len(n_sizes) > 1 and hoistable:
-            # Legacy inscan-plan hoisting, node-count sweeps only: sample
-            # every slot's gains up front instead of tracing the per-N
-            # `lax.switch` branches into the scan body (which multiplies
-            # the XLA program and its compile time — the very cost the
-            # padded N axis exists to remove). Stream-identical: each step
-            # key is split exactly as the slot fns would split it, and the
-            # k_h half feeds the same padded sampler. The dynamic-count
-            # sampler (one static-shape threefry program for all N) is
-            # preferred; the per-N `lax.switch` sampler is the fallback
-            # when the raw primitive is unavailable or a non-threefry PRNG
-            # is active.
-            from repro.core.mc import sampling
-
-            k_hs = jax.vmap(lambda k: jax.random.split(k)[0])(step_keys)
-            if sampling._dynamic_threefry_ok():
-                sample = lambda kh: sampling._sample_gains_dynamic_n(
-                    kh, fading, p, n_max_)
-            else:
-                sample = lambda kh: sampling._sample_gains_padded(
-                    kh, fading, p, n_sizes, n_max_)
-            h_all = jax.vmap(sample)(k_hs)
         carry0 = (t0, jnp.zeros_like(t0), jnp.float32(0.0))
         if use_ec:
             carry0 = (t0, jnp.zeros_like(t0),
@@ -349,7 +336,7 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
                     part_keys)
         carry_fin, (risks, cum_e) = jax.lax.scan(
             body, carry0,
-            (step_keys, h_all, data_keys, draws_all, idx_all, part_u))
+            (step_keys, data_keys, draws_all, idx_all, part_u))
         theta_fin = carry_fin[0]
         fin = risk_fn(row, theta_fin) if row_based else risk_fn(theta_fin)
         risks = jnp.concatenate([risks, fin[None]])
@@ -386,10 +373,14 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
             out_specs = (P("rows"), P("rows"))
         else:
             out_specs = (P("rows", "mc"), P("rows", "mc"))
+        # check_vma=False: the scan carries start from the replicated
+        # theta0 and turn device-varying after one step; every collective
+        # in the region is an explicit psum over 'mc', so the varying-axes
+        # type check has nothing left to protect
         seed_block = compat.shard_map(
             seed_block, mesh=mesh,
             in_specs=(P("mc"), P("rows"), P("rows"), P(), P("rows")),
-            out_specs=out_specs)
+            out_specs=out_specs, check_vma=False)
     return seed_block(seeds, params, betas, theta0, data)
 
 

@@ -36,8 +36,8 @@ from typing import Callable, Optional
 import jax
 
 # The CI-class container the scheduler is sized against (the same figure
-# the benchmark's MEM_BUDGET_GIB uses) — the fallback when the backend
-# does not report a device memory limit.
+# the benchmark's MEM_BUDGET_GIB uses) — the CPU backend's budget, which
+# reports no device memory limit.
 DEFAULT_MEMORY_BUDGET_BYTES = 2 * 2**30
 # Per-device working-set target for chunk sizing: chunks small enough to
 # run cache-resident on CPU-class devices (the measured regime of the
@@ -96,7 +96,8 @@ class ExecPlan:
                 divide the row count). 1 = rows stay on the seed mesh.
     keep_seed_curves: False reduces per-seed curves to (mean, ci95) on
                 device — Chan-merged moments under chunking.
-    ota_impl:   'auto' | 'pallas' | 'ref' routing of the OTA slot.
+    ota_impl:   'auto' | 'inline' | 'pallas' | 'interpret' | 'ref' routing
+                of the OTA slot (`slots.resolve_ota_impl`).
     retry:      a `RetryPolicy` for chunk-level fault isolation in
                 `exec.run_chunked` (None = fail fast, the legacy
                 behavior). Retried chunks replay their counter-based RNG
@@ -185,16 +186,20 @@ def resolve_seed_shards(plan: ExecPlan, seeds: int,
 
 
 def device_memory_budget_bytes() -> int:
-    """Per-device memory budget: the backend-reported limit when available
-    (TPU/GPU `memory_stats()['bytes_limit']`, at 80% headroom), else the
-    CI-class default the scheduler is sized against."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(0.8 * stats["bytes_limit"])
-    except Exception:
-        pass
-    return DEFAULT_MEMORY_BUDGET_BYTES
+    """Per-device memory budget: the backend-reported limit
+    (`memory_stats()['bytes_limit']`, at 80% headroom). The CPU backend
+    reports none and gets the CI-class default the scheduler is sized
+    against; an accelerator that does not report its limit is an error —
+    a budget guessed for a CPU container would mis-size every chunk."""
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        return DEFAULT_MEMORY_BUDGET_BYTES
+    stats = dev.memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        raise RuntimeError(
+            f"{dev.device_kind} ({dev.platform}) reports no bytes_limit in "
+            "memory_stats(); pass memory_budget_bytes= explicitly")
+    return int(0.8 * stats["bytes_limit"])
 
 
 def _divisors_desc(n: int) -> list:

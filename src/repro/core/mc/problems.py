@@ -323,12 +323,12 @@ def _logistic_sample_idx_row(row: dict, key: Array, b_max: int) -> Array:
 
     Index entry (n, j) draws as a SCALAR from
     `fold_in(fold_in(key, j), n)` rather than one (n_max, b_max)-shaped
-    draw: threefry streams are shape-dependent, so a shaped draw would
-    make each row's minibatch depend on the sweep-wide b_max AND n_max —
-    per-(lane, node) scalar keys keep every entry identical across all
-    sweep paddings, so one-compile fraction sweeps and node-count sweeps
-    both reproduce their dedicated runs row-for-row (the same invariant
-    `mc/sampling.py` maintains for the channel draws)."""
+    draw: a shaped draw's row-major layout would make each node's
+    minibatch depend on the sweep-wide b_max — per-(lane, node) scalar
+    keys keep every entry identical across all sweep paddings, so
+    one-compile fraction sweeps and node-count sweeps both reproduce their
+    dedicated runs row-for-row (the same invariant `mc/sampling.py`
+    maintains for the channel draws)."""
     n_max, k, _ = row["Xn"].shape
     nodes = jnp.arange(n_max, dtype=jnp.uint32)
     lane_keys = [jax.random.fold_in(key, j) for j in range(b_max)]

@@ -4,30 +4,24 @@ Everything here is a *traceable twin* of a host-side reference sampler
 (`repro.core.channel.sample_gains` / `sample_complex_gains`,
 `jax.random.split`, `jax.random.normal`) — same key-split order, same draw
 shapes — so engine trajectories reproduce the reference simulators under a
-fixed seed. Three tiers per draw:
+fixed seed.
 
-  * plain shaped draws (`_sample_gains`, `_sample_complex_gains`) for a
-    single static node count;
-  * padded `lax.switch` variants (`*_padded`, `_normal_padded`) that sample
-    at each row's true static shape and zero-pad to N_max (threefry streams
-    are shape-dependent, so padded-then-masked sampling would change every
-    row's stream);
-  * dynamic-count variants (`*_dynamic_n`, `_antenna_keys`) that reproduce
-    the shaped draw bit-for-bit in ONE static-shape program by calling the
-    raw threefry2x32 hash with counter vectors computed from the row's true
-    count as *data* — no per-count branches, compile time independent of
-    the sweep size. Only valid under the default threefry PRNG
-    (`_dynamic_threefry_ok`); callers fall back to the switch tier.
-
-`_row_gains` / `_row_complex_gains` pick the fastest valid tier.
+Node-count and antenna-count sweeps pad every row to N_max (M_max). The
+partitionable threefry layout (`jax_threefry_partitionable`, on by
+default) hashes each element from its own flat index, so draws are
+prefix-stable: `normal(k, (n, d)) == normal(k, (n_max, d))[:n]`,
+`uniform(k, (n,)) == uniform(k, (n_max,))[:n]` and
+`split(k, m) == split(k, m_max)[:m]`. A row's draw at its true count is
+therefore the N_max draw with the lanes beyond it masked — one static-shape
+program for every count in the sweep (`_row_gains`, `_row_complex_gains`,
+`_row_normal`, `_antenna_keys`). `_check_prefix_stable` refuses a
+configuration in which that equality would not hold.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro import compat
 
 Array = jax.Array
 
@@ -105,236 +99,59 @@ def _sample_complex_gains(key: Array, fading: str, p: dict,
             (h * jnp.sin(phi)).astype(jnp.float32))
 
 
-def _sample_gains_padded(key: Array, fading: str, p: dict,
-                         n_sizes: tuple, n_max: int,
-                         phase_zero: bool = False) -> Array:
-    """(n_max,) gains whose first n entries equal the unpadded (n,) draw.
-
-    Threefry streams depend on the draw shape, so sampling (n_max,) and
-    masking would NOT reproduce the per-N reference draws. Instead the
-    row's true node count (p['n_idx'] indexes the static `n_sizes`) selects
-    a branch that samples at the true static shape and zero-pads. With a
-    single full-size branch this is the plain sampler (no switch traced).
-    """
-    if len(n_sizes) == 1 and n_sizes[0] == n_max:
-        return _sample_gains(key, fading, p, (n_max,), phase_zero)
-    branches = [
-        (lambda k, n=n: jnp.pad(_sample_gains(k, fading, p, (n,),
-                                              phase_zero),
-                                (0, n_max - n)))
-        for n in n_sizes
-    ]
-    return jax.lax.switch(p["n_idx"], branches, key)
+def _check_prefix_stable() -> None:
+    """Padded sweeps reproduce per-count runs only under the partitionable
+    threefry layout; refuse any other PRNG configuration."""
+    if not (jax.config.jax_threefry_partitionable
+            and jax.config.jax_default_prng_impl == "threefry2x32"):
+        raise ValueError(
+            "padded node/antenna-count sweeps need the default threefry "
+            "PRNG with jax_threefry_partitionable=True (prefix-stable "
+            "draws); run one count per call otherwise")
 
 
-def _sample_complex_gains_padded(key: Array, fading: str, p: dict,
-                                 n_sizes: tuple, n_max: int) -> tuple:
-    """(a, b) complex-gain parts, zero-padded like `_sample_gains_padded`
-    (per-N branches sample at the true static shape)."""
-    if len(n_sizes) == 1 and n_sizes[0] == n_max:
-        return _sample_complex_gains(key, fading, p, (n_max,))
-    branches = [
-        (lambda k, n=n: jnp.pad(
-            jnp.stack(_sample_complex_gains(k, fading, p, (n,))),
-            ((0, 0), (0, n_max - n))))
-        for n in n_sizes
-    ]
-    ab = jax.lax.switch(p["n_idx"], branches, key)
-    return ab[0], ab[1]
-
-
-def _normal_padded(key: Array, n_idx: Array, n_sizes: tuple, n_max: int,
-                   d: int, dtype) -> Array:
-    """(n_max, d) normal draw matching the unpadded (n, d) draw per row
-    (same shape-dependent-stream issue as `_sample_gains_padded`)."""
-    if len(n_sizes) == 1 and n_sizes[0] == n_max:
-        return jax.random.normal(key, (n_max, d), dtype=dtype)
-    branches = [
-        (lambda k, n=n: jnp.pad(jax.random.normal(k, (n, d), dtype=dtype),
-                                ((0, n_max - n), (0, 0))))
-        for n in n_sizes
-    ]
-    return jax.lax.switch(n_idx, branches, key)
-
-
-# --------------------------------------------------------------------------
-# dynamic-length draws with static shapes (node-count sweeps, fast path)
-#
-# Threefry draws depend on the requested shape: `uniform(key, (n,))` hashes
-# counter pairs (j, j + ceil(n/2)), so every distinct N needs its own draw
-# program, and the `lax.switch` over those programs is what makes the padded
-# sweep expensive to compile. But the counters are just uint32 DATA — by
-# calling the raw threefry2x32 primitive on counter vectors computed from a
-# *traced* n, one static-shape (n_max) program reproduces the (n,)-shaped
-# draw bit-for-bit in lanes [0, n). The bits->float transforms below are
-# copied from `jax._src.random._uniform` / `_normal_real` so the values
-# match exactly. Only valid for the default threefry PRNG — callers must
-# check `compat.threefry_is_default()` and fall back to the switch sampler.
-# --------------------------------------------------------------------------
-def _dynamic_bits(kd: Array, size: Array, out_max: int) -> Array:
-    """uint32 bits equal to `random_bits(key, 32, (size,))` in lanes
-    [0, size); `size` is traced (<= out_max), `out_max` static."""
-    m_max = (out_max + 1) // 2
-    m = (size + 1) // 2  # half-width of the counter vector (incl. odd pad)
-    i = jnp.arange(m_max, dtype=jnp.int32)
-    x0 = i.astype(jnp.uint32)
-    # second counter half: j + m, with the odd-size pad slot hashed on 0
-    x1 = jnp.where(i + m < size, i + m, 0).astype(jnp.uint32)
-    # merge batch dims BEFORE the bind: the primitive's batching rule
-    # mis-broadcasts when keys are vmapped over different axes (seeds,
-    # steps) than the counts (configs). `| zero` stamps every operand with
-    # the union of batch dims through ordinary elementwise batching (x1
-    # carries the config dims via `m`; kd carries the seed/step dims).
-    zero = (kd[0] & jnp.uint32(0)) | (x1 & jnp.uint32(0))
-    o0, o1 = compat.threefry2x32(kd[0] | zero, kd[1] | zero,
-                                 x0 | zero, x1 | zero)
-    j = jnp.arange(out_max, dtype=jnp.int32)
-    bits0 = o0[jnp.minimum(j, m_max - 1)]
-    bits1 = o1[jnp.clip(j - m, 0, m_max - 1)]
-    return jnp.where(j < m, bits0, bits1)
-
-
-_F32_ONE_BITS = np.float32(1.0).view(np.uint32)
-_NORMAL_LO = np.nextafter(np.float32(-1.0), np.float32(0.0))
-
-
-def _bits_to_u01(bits: Array) -> Array:
-    """uint32 bits -> uniform [0, 1) floats, as `_uniform` builds them."""
-    fb = (bits >> jnp.uint32(9)) | jnp.uint32(_F32_ONE_BITS)
-    return jax.lax.bitcast_convert_type(fb, jnp.float32) - jnp.float32(1.0)
-
-
-def _u01_to_uniform(u01: Array, minval, maxval) -> Array:
-    return jnp.maximum(minval, u01 * (maxval - minval) + minval)
-
-
-def _u01_to_normal(u01: Array) -> Array:
-    lo = jnp.float32(_NORMAL_LO)
-    u = jnp.maximum(lo, u01 * (jnp.float32(1.0) - lo) + lo)
-    return jnp.float32(np.sqrt(2.0)) * jax.lax.erf_inv(u)
-
-
-def _normal_dynamic_n(key: Array, n: Array, n_max: int, d: int) -> Array:
-    """Zero-padded (n_max, d) twin of `normal(key, (n, d))` for traced n
-    (the fdm per-node noise on node-count sweeps) — same counts-as-data
-    trick as `_sample_gains_dynamic_n`, so the scan body stays free of
-    per-N `lax.switch` branches."""
-    kd = jax.random.key_data(key)
-    z = _u01_to_normal(_bits_to_u01(_dynamic_bits(kd, n * d, n_max * d)))
-    z = jnp.where(jnp.arange(n_max * d) < n * d, z, jnp.float32(0.0))
-    return z.reshape(n_max, d)
-
-
-def _sample_magnitude_dynamic_n(kd_mag: Array, fading: str, p: dict,
-                                n: Array, n_max: int) -> Array:
-    """Dynamic-count twin of `_sample_magnitude` (traced n, static n_max);
-    lanes ≥ n are garbage until the caller masks them."""
-    scale = p["scale"]
-    if fading == "equal":
-        return jnp.broadcast_to(scale.astype(jnp.float32), (n_max,))
-    if fading == "rayleigh":
-        u01 = _bits_to_u01(_dynamic_bits(kd_mag, n, n_max))
-        u = _u01_to_uniform(u01, jnp.float32(1e-12), jnp.float32(1.0))
-        return scale * jnp.sqrt(-2.0 * jnp.log(u))
-    if fading == "rician":
-        nu = jnp.sqrt(p["rician_k"] * 2.0) * scale
-        z = _u01_to_normal(_bits_to_u01(
-            _dynamic_bits(kd_mag, 2 * n, 2 * n_max)))
-        xy = z.reshape(n_max, 2) * scale
-        return jnp.sqrt((xy[..., 0] + nu) ** 2 + xy[..., 1] ** 2)
-    if fading == "lognormal":
-        z = _u01_to_normal(_bits_to_u01(_dynamic_bits(kd_mag, n, n_max)))
-        return jnp.exp(scale * z)
-    raise ValueError(f"unknown fading model: {fading}")
-
-
-def _sample_gains_dynamic_n(key: Array, fading: str, p: dict,
-                            n_max: int, phase_zero: bool = False) -> Array:
-    """Bit-exact twin of `_sample_gains(key, fading, p, (n,))` zero-padded
-    to (n_max,), with n = p['n_nodes'] traced — one static-shape program
-    covers every node count in the sweep. `phase_zero` skips the phase
-    stream statically (value-identical; see `_sample_gains`)."""
-    n = p["n_nodes"].astype(jnp.int32)
-    k_mag, k_ph = jax.random.split(key)
-    h = _sample_magnitude_dynamic_n(jax.random.key_data(k_mag), fading, p,
-                                    n, n_max)
-    if not phase_zero:
-        a = p["phase_error_max"]
-        phi = _u01_to_uniform(
-            _bits_to_u01(_dynamic_bits(jax.random.key_data(k_ph), n, n_max)),
-            -a, a)
-        h = h * jnp.cos(phi)
-    h = h.astype(jnp.float32)
-    return jnp.where(jnp.arange(n_max) < n, h, jnp.float32(0.0))
-
-
-def _sample_complex_gains_dynamic_n(key: Array, fading: str, p: dict,
-                                    n_max: int) -> tuple:
-    """Dynamic-count twin of `_sample_complex_gains(key, fading, p, (n,))`
-    zero-padded to (n_max,) — the blind family's per-antenna gain draw on
-    node-count sweeps."""
-    n = p["n_nodes"].astype(jnp.int32)
-    k_mag, k_ph = jax.random.split(key)
-    h = _sample_magnitude_dynamic_n(jax.random.key_data(k_mag), fading, p,
-                                    n, n_max)
-    phi = _u01_to_uniform(
-        _bits_to_u01(_dynamic_bits(jax.random.key_data(k_ph), n, n_max)),
-        jnp.float32(-np.pi), jnp.float32(np.pi))
-    lane = jnp.arange(n_max) < n
-    a = jnp.where(lane, (h * jnp.cos(phi)).astype(jnp.float32), 0.0)
-    b = jnp.where(lane, (h * jnp.sin(phi)).astype(jnp.float32), 0.0)
-    return a, b
-
-
-def _dynamic_threefry_ok() -> bool:
-    """Counts-as-data fast paths need the raw primitive AND the default
-    threefry PRNG (the bit-level replication is only valid then)."""
-    return compat.threefry2x32 is not None and compat.threefry_is_default()
+def _node_mask(x: Array, p: dict, n_sizes: tuple, n_max: int) -> Array:
+    """Zero the lanes of a node-leading (n_max, ...) draw beyond this row's
+    true node count `p['n_nodes']` — a no-op for single-N calls, where
+    every row transmits at n_max."""
+    if len(n_sizes) == 1:
+        return x
+    _check_prefix_stable()
+    lane = jnp.arange(n_max) < p["n_nodes"]
+    return jnp.where(lane.reshape((n_max,) + (1,) * (x.ndim - 1)), x,
+                     jnp.zeros((), x.dtype))
 
 
 def _row_gains(key: Array, fading: str, p: dict, n_sizes: tuple,
                n_max: int, phase_zero: bool = False) -> Array:
-    """This row's (n_max,) zero-padded slot gains: dynamic-count program
-    when available (no per-N branches), per-N `lax.switch` otherwise."""
-    if len(n_sizes) > 1 and _dynamic_threefry_ok():
-        return _sample_gains_dynamic_n(key, fading, p, n_max, phase_zero)
-    return _sample_gains_padded(key, fading, p, n_sizes, n_max, phase_zero)
+    """This row's (n_max,) slot gains, zero beyond its true node count:
+    the n_max-shaped draw, masked (equal to the (n,)-shaped draw in lanes
+    [0, n) — see the module docstring)."""
+    return _node_mask(_sample_gains(key, fading, p, (n_max,), phase_zero),
+                      p, n_sizes, n_max)
 
 
 def _row_complex_gains(key: Array, fading: str, p: dict, n_sizes: tuple,
                        n_max: int) -> tuple:
     """Complex counterpart of `_row_gains` for the blind family."""
-    if len(n_sizes) > 1 and _dynamic_threefry_ok():
-        return _sample_complex_gains_dynamic_n(key, fading, p, n_max)
-    return _sample_complex_gains_padded(key, fading, p, n_sizes, n_max)
+    a, b = _sample_complex_gains(key, fading, p, (n_max,))
+    return (_node_mask(a, p, n_sizes, n_max),
+            _node_mask(b, p, n_sizes, n_max))
 
 
-def _antenna_keys(key: Array, m_sizes: tuple, p: dict) -> Array:
-    """(m_max,) antenna keys whose first m entries (m = this row's true
-    antenna count, `p['n_antennas']`) equal `jax.random.split(key, m)`.
+def _row_normal(key: Array, p: dict, n_sizes: tuple, n_max: int, d: int,
+                dtype) -> Array:
+    """(n_max, d) standard normals, rows beyond the true node count zeroed
+    (the fdm per-node noise)."""
+    return _node_mask(jax.random.normal(key, (n_max, d), dtype=dtype),
+                      p, n_sizes, n_max)
 
-    Antenna counts suffer the same shape-dependent-stream problem as node
-    counts: `split` is itself a threefry draw over `iota(2m)` counters, so
-    splitting at m_max and masking would change every row's stream. The
-    fast path replays the original split layout with the row's count as
-    DATA (`_dynamic_bits` over 2m counters, reshaped (m_max, 2)); its
-    validity is verified empirically by `compat.threefry_split_is_original`
-    (False under `jax_threefry_partitionable`). The fallback is a
-    `lax.switch` over the distinct static counts. Lanes ≥ m hold
-    well-formed garbage keys — callers mask the antenna axis."""
-    m_max = max(m_sizes)
-    if len(m_sizes) == 1:
-        return jax.random.split(key, m_max)
-    if compat.threefry2x32 is not None \
-            and compat.threefry_split_is_original():
-        m = p["n_antennas"].astype(jnp.int32)
-        bits = _dynamic_bits(jax.random.key_data(key), 2 * m, 2 * m_max)
-        return jax.random.wrap_key_data(bits.reshape(m_max, 2))
-    branches = [
-        (lambda k, m=m: jnp.pad(
-            jax.random.key_data(jax.random.split(k, m)),
-            ((0, m_max - m), (0, 0))))
-        for m in m_sizes
-    ]
-    return jax.random.wrap_key_data(
-        jax.lax.switch(p["m_idx"], branches, key))
+
+def _antenna_keys(key: Array, m_sizes: tuple) -> Array:
+    """(m_max,) antenna keys whose first m entries equal
+    `jax.random.split(key, m)` for every m <= m_max (split is prefix-stable
+    too). Lanes >= a row's true antenna count hold well-formed keys that
+    callers mask out on the antenna axis."""
+    if len(m_sizes) > 1:
+        _check_prefix_stable()
+    return jax.random.split(key, max(m_sizes))

@@ -25,12 +25,10 @@ import jax.numpy as jnp
 
 from repro.core.mc.sampling import (
     _antenna_keys,
-    _dynamic_threefry_ok,
     _magnitude_m2,
-    _normal_dynamic_n,
-    _normal_padded,
     _row_complex_gains,
     _row_gains,
+    _row_normal,
 )
 
 Array = jax.Array
@@ -46,12 +44,9 @@ class SlotCtx:
     n_antennas: static broadcast antenna count (None = single antenna,
               RNG-identical to `GBMASimulator`).
     m_sizes:  distinct per-row antenna counts (static; empty = broadcast).
-    h_slot:   this slot's pre-sampled gain vector when the legacy inscan
-              plan hoisted the gain sampling out of the scan (node-count
-              sweeps); drawn from exactly the k_h the slot fn would have
-              split off.
-    ota_impl: 'inline' (engine einsum) or 'pallas'/'ref'/'auto' to route
-              the OTA superposition through `repro.kernels.ota`.
+    ota_impl: a resolved `resolve_ota_impl` value: 'inline' (engine
+              einsum), or 'pallas' / 'interpret' / 'ref' to route the OTA
+              superposition through `repro.kernels.ota`.
     phase_zero: static promise that every row's phase_error_max is 0 —
               lets the hoisted draw twins skip the precoded-phase stream
               (value-identical; see `sampling._sample_gains`).
@@ -70,10 +65,45 @@ class SlotCtx:
     m_sizes: tuple
     invert_channel: bool
     h_min: float
-    h_slot: Optional[Array] = None
     ota_impl: str = "inline"
     phase_zero: bool = False
     draws: Optional[dict] = None
+
+
+OTA_IMPLS = ("auto", "inline", "pallas", "interpret", "ref")
+
+
+def resolve_ota_impl(ota_impl: str, n_sizes: tuple = (1,)) -> str:
+    """The one routing rule for the single-antenna OTA superposition,
+    shared by the engine and the transport layer.
+
+    'auto' → 'pallas' on TPU when the kernel applies, 'inline' otherwise.
+    'pallas' compiles the Mosaic TPU kernel and is refused off-TPU;
+    'interpret' runs the same kernel in the Pallas interpreter (tests on
+    CPU ask for it explicitly); 'ref' is the kernel's jnp oracle. The
+    kernel normalizes by a STATIC node count, so it only applies when
+    every row transmits at the same (full, unpadded) N — an explicit
+    kernel impl on a padded node-count sweep is an error rather than a
+    silent wrong normalization.
+    """
+    if ota_impl not in OTA_IMPLS:
+        raise ValueError(
+            f"ota_impl must be one of {OTA_IMPLS}, got {ota_impl!r}")
+    eligible = len(n_sizes) == 1
+    on_tpu = jax.default_backend() == "tpu"
+    if ota_impl == "auto":
+        return "pallas" if (eligible and on_tpu) else "inline"
+    if ota_impl != "inline" and not eligible:
+        raise ValueError(
+            f"ota_impl={ota_impl!r} needs a single node count per call "
+            f"(got n_sizes={n_sizes}): the OTA kernel normalizes by the "
+            "static N, which a padded node-count sweep does not have")
+    if ota_impl == "pallas" and not on_tpu:
+        raise ValueError(
+            "ota_impl='pallas' compiles the TPU kernel, but the backend is "
+            f"{jax.default_backend()!r}; use 'interpret' to run the kernel "
+            "in the Pallas interpreter")
+    return ota_impl
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,11 +121,6 @@ class AlgoSpec:
     nesterov:       gradient evaluated at the lookahead θ − βγm.
     error_feedback: row carries the per-node residual + power-budget
                     truncation in the scan (`blind_ec` semantics).
-    hoist_gains(invert_channel) -> bool: whether the slot's scalar-gain
-                    draw may be hoisted out of the scan on node-count
-                    sweeps under the LEGACY inscan plan (single-antenna
-                    only; the engine checks the antenna config
-                    separately).
     hoist_draws(step_keys, ctx, n_max, d) -> dict: the algorithm's draw
                     twin for the execution layer's hoisted RNG plan
                     (`mc/exec.py`): materializes every random stream the
@@ -116,7 +141,6 @@ class AlgoSpec:
     uses_gamma: bool = False
     nesterov: bool = False
     error_feedback: bool = False
-    hoist_gains: Callable[[bool], bool] = staticmethod(lambda inv: False)
     hoist_draws: Optional[Callable] = None
     theorem1: bool = False
 
@@ -129,7 +153,6 @@ def register_algo(name: str,
                   *, ota: bool = False, blind: bool = False,
                   uses_gamma: bool = False, nesterov: bool = False,
                   error_feedback: bool = False,
-                  hoist_gains: Optional[Callable[[bool], bool]] = None,
                   hoist_draws: Optional[Callable] = None,
                   theorem1: bool = False,
                   overwrite: bool = False) -> AlgoSpec:
@@ -143,7 +166,6 @@ def register_algo(name: str,
     spec = AlgoSpec(name=name, slot_fn=slot_fn, ota=ota, blind=blind,
                     uses_gamma=uses_gamma, nesterov=nesterov,
                     error_feedback=error_feedback,
-                    hoist_gains=hoist_gains or (lambda inv: False),
                     hoist_draws=hoist_draws,
                     theorem1=theorem1)
     ALGO_REGISTRY[name] = spec
@@ -177,7 +199,7 @@ def _step_antenna_keys(key: Array, ctx: SlotCtx) -> Array:
     """One slot's antenna keys: the per-row counts-as-data replay when the
     antenna count is row data, the plain split for a static M."""
     if ctx.m_sizes:
-        return _antenna_keys(key, ctx.m_sizes, ctx.p)
+        return _antenna_keys(key, ctx.m_sizes)
     return jax.random.split(key, ctx.n_antennas)
 
 
@@ -216,17 +238,17 @@ def _ota_draw(key: Array, ctx: SlotCtx, n_max: int, d: int) -> dict:
     return out
 
 
-def _ota_slot(g: Array, key: Array, ctx: SlotCtx, h_slot=None) -> Array:
+def _ota_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
     """Single-antenna OTA superposition (Eq. 8): v = (1/N) Σ h_n g_n + w.
 
-    slot key → (k_h, k_w); k_h draws the (n_max,) gains unless the caller
-    hoisted them (`ctx.draws` under the hoisted plan, `h_slot` under the
-    legacy N-sweep hoist), k_w the (d,) edge noise — split-for-split
-    identical to `gbma.ota_aggregate`. With `ctx.ota_impl != 'inline'` the
-    superposition + noise-add routes through the tiled
-    `repro.kernels.ota.ota_edge_aggregate` kernel (pallas on TPU, jnp
-    oracle otherwise); the traced noise std folds into the noise operand so
-    the kernel's static `noise_scale` stays 1.
+    slot key → (k_h, k_w); k_h draws the (n_max,) gains unless the hoisted
+    plan supplied them (`ctx.draws`), k_w the (d,) edge noise —
+    split-for-split identical to `gbma.ota_aggregate`. With
+    `ctx.ota_impl != 'inline'` the superposition + noise-add routes through
+    the tiled `repro.kernels.ota.ota_edge_aggregate` kernel: compiled for
+    the TPU ('pallas'), run by the Pallas interpreter ('interpret'), or the
+    jnp oracle ('ref'); the traced noise std folds into the noise operand
+    so the kernel's static `noise_scale` stays 1.
     """
     p = ctx.p
     if ctx.draws is not None:
@@ -236,8 +258,7 @@ def _ota_slot(g: Array, key: Array, ctx: SlotCtx, h_slot=None) -> Array:
             h = _deterministic_gains(key, ctx, g.shape[0])
     else:
         k_h, k_w = jax.random.split(key)
-        h = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, g.shape[0]) \
-            if h_slot is None else h_slot
+        h = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, g.shape[0])
         w = jax.random.normal(k_w, (g.shape[1],), dtype=g.dtype)
     std = p["noise_std"] / (p["n_nodes"] * jnp.sqrt(p["energy"]))
     if ctx.ota_impl != "inline":
@@ -248,8 +269,9 @@ def _ota_slot(g: Array, key: Array, ctx: SlotCtx, h_slot=None) -> Array:
         # out_dtype matches the inline einsum's promotion (f32 gains x g),
         # so bf16-transmit blocks still emit an f32 received update.
         return ota_edge_aggregate(g, h, std * w, noise_scale=1.0,
-                                  impl=ctx.ota_impl,
-                                  interpret=jax.default_backend() != "tpu",
+                                  impl="ref" if ctx.ota_impl == "ref"
+                                  else "pallas",
+                                  interpret=ctx.ota_impl == "interpret",
                                   out_dtype=jnp.promote_types(
                                       g.dtype, jnp.float32))
     v = jnp.einsum("n,nd->d", h, g) / p["n_nodes"]
@@ -291,7 +313,7 @@ def _gbma_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
                      < p["n_antennas"]).astype(v.dtype)
             return jnp.einsum("m,md->d", amask, v) / p["n_antennas"]
         return jnp.mean(v, axis=0)
-    return _ota_slot(g, key, ctx, ctx.h_slot)
+    return _ota_slot(g, key, ctx)
 
 
 def _centralized_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
@@ -354,13 +376,8 @@ def _fdm_draw(key: Array, ctx: SlotCtx, n_max: int, d: int) -> dict:
     unconsumed, matching `baselines.FDMGD`) — the (n_max,) gains."""
     p = ctx.p
     k_h, k_w = jax.random.split(key)
-    if len(ctx.n_sizes) > 1 and _dynamic_threefry_ok():
-        raw = _normal_dynamic_n(k_w, p["n_nodes"].astype(jnp.int32),
-                                n_max, d)
-    else:
-        raw = _normal_padded(k_w, p["n_idx"], ctx.n_sizes, n_max, d,
-                             jnp.float32)
-    out = {"noise_raw": raw}
+    out = {"noise_raw": _row_normal(k_w, p, ctx.n_sizes, n_max, d,
+                                    jnp.float32)}
     if not ctx.invert_channel and not _gains_deterministic(ctx):
         out["h"] = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, n_max,
                               ctx.phase_zero)
@@ -385,16 +402,10 @@ def _fdm_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
             h = _deterministic_gains(key, ctx, n_max)
     else:
         k_h, k_w = jax.random.split(key)
-        if len(ctx.n_sizes) > 1 and _dynamic_threefry_ok():
-            raw = _normal_dynamic_n(
-                k_w, p["n_nodes"].astype(jnp.int32), n_max, g.shape[1])
-        else:
-            raw = _normal_padded(
-                k_w, p["n_idx"], ctx.n_sizes, n_max, g.shape[1], g.dtype)
+        raw = _row_normal(k_w, p, ctx.n_sizes, n_max, g.shape[1], g.dtype)
         h = None
         if not ctx.invert_channel:
-            h = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, n_max) \
-                if ctx.h_slot is None else ctx.h_slot
+            h = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, n_max)
     noise = p["noise_std"] / jnp.sqrt(p["energy"]) * raw
     if ctx.invert_channel:
         rx = g + noise
@@ -430,8 +441,7 @@ def _power_control_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
             h = _deterministic_gains(key, ctx, n_max)
     else:
         k_h, k_w = jax.random.split(key)
-        h = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, n_max) \
-            if ctx.h_slot is None else ctx.h_slot
+        h = _row_gains(k_h, ctx.fading, p, ctx.n_sizes, n_max)
         w_raw = jax.random.normal(k_w, (g.shape[1],), dtype=g.dtype)
     active = (h >= ctx.h_min).astype(g.dtype) * ctx.mask
     n_active = jnp.maximum(jnp.sum(active), 1.0)
@@ -444,20 +454,15 @@ def _power_control_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
 # built-in registrations (order defines the historical ALGOS tuple)
 # --------------------------------------------------------------------------
 register_algo("gbma", _gbma_slot, ota=True,
-              hoist_gains=lambda inv: True,
               hoist_draws=_gbma_hoist_draws, theorem1=True)
 register_algo("centralized", _centralized_slot)
-register_algo("fdm", _fdm_slot, hoist_gains=lambda inv: not inv,
-              hoist_draws=_fdm_hoist_draws)
+register_algo("fdm", _fdm_slot, hoist_draws=_fdm_hoist_draws)
 register_algo("power_control", _power_control_slot,
-              hoist_gains=lambda inv: True,
               hoist_draws=_pc_hoist_draws)
 register_algo("momentum", _gbma_slot, ota=True, uses_gamma=True,
-              hoist_gains=lambda inv: True,
               hoist_draws=_gbma_hoist_draws)
 register_algo("nesterov", _gbma_slot, ota=True, uses_gamma=True,
-              nesterov=True, hoist_gains=lambda inv: True,
-              hoist_draws=_gbma_hoist_draws)
+              nesterov=True, hoist_draws=_gbma_hoist_draws)
 register_algo("blind", _blind_slot, blind=True,
               hoist_draws=_blind_hoist_draws)
 register_algo("blind_ec", _blind_slot, blind=True, error_feedback=True,
@@ -533,7 +538,7 @@ def slot_update_block(algo: str, g: Array, key: Array, ctx: SlotCtx,
 def _slot_update(g: Array, key: Array, *, algo: str, fading: str, p: dict,
                  mask: Array, n_sizes: tuple, n_antennas: Optional[int],
                  m_sizes: tuple, invert_channel: bool, h_min: float,
-                 h_slot=None, ota_impl: str = "inline") -> Array:
+                 ota_impl: str = "inline") -> Array:
     """Back-compat wrapper over the registry dispatch: one MAC slot,
     transmitted per-node vectors (n_max, d) -> received update (d,).
 
@@ -553,5 +558,5 @@ def _slot_update(g: Array, key: Array, *, algo: str, fading: str, p: dict,
     ctx = SlotCtx(fading=fading, p=p, mask=mask, n_sizes=n_sizes,
                   n_antennas=n_antennas, m_sizes=m_sizes,
                   invert_channel=invert_channel, h_min=h_min,
-                  h_slot=h_slot, ota_impl=ota_impl)
+                  ota_impl=ota_impl)
     return ALGO_REGISTRY[algo].slot_fn(g, key, ctx)

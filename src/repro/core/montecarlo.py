@@ -7,8 +7,8 @@ package split along its natural layers:
     `PROBLEMS` registry (`register_problem`) and the library constructors
     (`quadratic_mc_problem`, `localization_mc_problem`,
     `logistic_mc_problem`).
-  * `repro.core.mc.sampling` — the reference-twin RNG samplers (padded /
-    dynamic-count threefry draws, antenna key replay).
+  * `repro.core.mc.sampling` — the reference-twin RNG samplers (padded
+    sweeps draw at N_max / M_max and mask).
   * `repro.core.mc.slots`    — per-slot algorithm updates behind
     `register_algo` (`ALGOS` is derived from the registry) + the
     `hoist_draws` RNG-plan twins.

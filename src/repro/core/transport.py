@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from repro.compat import tree_flatten, tree_map, tree_unflatten
 from repro.core.channel import ChannelConfig
 from repro.core.mc.slots import (ALGO_REGISTRY, AlgoSpec, SlotCtx,
-                                 slot_update_block)
+                                 resolve_ota_impl, slot_update_block)
 
 Array = jax.Array
 PyTree = Any
@@ -96,8 +96,9 @@ class TransportConfig:
       (the untiled reference).
     transmit_dtype: None (f32 faithful baseline) or 'bfloat16' — cast the
       transmitted blocks, keep gains/noise/accumulation f32.
-    ota_impl: 'inline' | 'auto' | 'pallas' | 'ref' for the single-antenna
-      OTA superposition ('auto' = pallas on TPU, inline otherwise).
+    ota_impl: 'inline' | 'auto' | 'pallas' | 'interpret' | 'ref' for the
+      single-antenna OTA superposition (`slots.resolve_ota_impl`: 'auto' =
+      pallas on TPU, inline otherwise).
     mc_steps: when set, `step_key` replays the engine's
       `split(key(seed), mc_steps)` slot-key schedule for trajectory parity
       with `run_mc`; None uses the training stack's `fold_in` schedule.
@@ -205,18 +206,7 @@ def _params_dict(cfg: TransportConfig) -> dict:
         "phase_error_max": f32(ch.phase_error_max),
         "rician_k": f32(ch.rician_k),
         "n_nodes": f32(cfg.n_nodes),
-        "n_idx": jnp.asarray(0, jnp.int32),
     }
-
-
-def _resolve_ota_impl(cfg: TransportConfig) -> str:
-    if cfg.ota_impl not in ("inline", "auto", "pallas", "ref"):
-        raise ValueError(
-            f"ota_impl must be 'inline', 'auto', 'pallas' or 'ref', "
-            f"got {cfg.ota_impl!r}")
-    if cfg.ota_impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "inline"
-    return cfg.ota_impl
 
 
 def make_ctx(cfg: TransportConfig, spec: AlgoSpec) -> SlotCtx:
@@ -231,7 +221,7 @@ def make_ctx(cfg: TransportConfig, spec: AlgoSpec) -> SlotCtx:
         mask=jnp.ones((n,), jnp.float32), n_sizes=(n,),
         n_antennas=cfg.n_antennas, m_sizes=(),
         invert_channel=cfg.invert_channel, h_min=cfg.h_min,
-        ota_impl=_resolve_ota_impl(cfg),
+        ota_impl=resolve_ota_impl(cfg.ota_impl),
         phase_zero=(cfg.channel.phase_error_max == 0.0))
 
 

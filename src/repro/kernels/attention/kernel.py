@@ -11,6 +11,11 @@ the grid walks (batch*heads, q_blocks, kv_blocks) with the kv dimension
 innermost/sequential; running max/denominator and the output accumulator live
 in VMEM scratch. Block shapes are (128, head_dim) / (128, head_dim), keeping
 the MXU matmul dims at the native 128 alignment. Accumulation is fp32.
+
+With `with_lse=True` the kernel also emits each row's log-sum-exp
+(m + log l), the one residual the flash backward
+(`repro.models.flash_vjp`) needs besides the output — the custom VJP in
+`ops.py` pairs this forward with that backward.
 """
 from __future__ import annotations
 
@@ -26,10 +31,13 @@ DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 scale: float, causal: bool, window: int | None,
-                 softcap: float | None, block_q: int, block_k: int,
-                 n_kv_blocks: int):
+def _attn_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
+                 window: int | None, softcap: float | None, block_q: int,
+                 block_k: int, n_kv_blocks: int, with_lse: bool):
+    if with_lse:
+        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -92,12 +100,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     def _finalize():
         l = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
         o_ref[0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        if with_lse:
+            lse_ref[0, ...] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "causal", "window", "softcap", "block_q",
-                     "block_k", "interpret"),
+                     "block_k", "interpret", "with_lse"),
 )
 def flash_attention_kernel(
     q: jax.Array,  # (BH, Sq, d)
@@ -111,7 +121,10 @@ def flash_attention_kernel(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
-) -> jax.Array:
+    with_lse: bool = False,
+):
+    """(BH, Sq, d) attention output; with `with_lse` also the (BH, Sq, 1)
+    f32 row log-sum-exp."""
     bh, sq, d = q.shape
     skv = k.shape[1]
     block_q = min(block_q, sq)
@@ -130,8 +143,16 @@ def flash_attention_kernel(
         block_q=block_q,
         block_k=block_k,
         n_kv_blocks=n_kv_blocks,
+        with_lse=with_lse,
     )
-    return pl.pallas_call(
+    out_specs = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    out_shape = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
+    if with_lse:
+        out_specs = [out_specs,
+                     pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32)]
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -139,8 +160,8 @@ def flash_attention_kernel(
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -148,3 +169,4 @@ def flash_attention_kernel(
         ],
         interpret=interpret,
     )(q, k, v)
+    return tuple(out) if with_lse else out

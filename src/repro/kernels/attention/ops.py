@@ -3,6 +3,11 @@
 Handles GQA head grouping ((B, Hq, S, d) queries vs (B, Hkv, S, d) kv),
 backend dispatch (Pallas on TPU, blockwise-jnp on CPU), and padding of
 sequence lengths to block boundaries.
+
+Autodiff cannot see through a `pallas_call`, so the Pallas path carries a
+custom VJP: the forward is the kernel (which also emits the row
+log-sum-exp), the backward is the flash backward of
+`repro.models.flash_vjp` — the same one the `opt_flash_vjp` jnp path uses.
 """
 from __future__ import annotations
 
@@ -13,6 +18,39 @@ import jax.numpy as jnp
 
 from repro.kernels.attention.kernel import flash_attention_kernel
 from repro.kernels.attention.ref import attention_ref
+from repro.models.flash_vjp import _flash_bwd
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _pallas_attention(q, k, v, scale, causal, window, softcap, block_q,
+                      block_k, interpret):
+    """(BH, S, d) flash attention through the Pallas kernel."""
+    return flash_attention_kernel(
+        q, k, v, scale=scale, causal=causal, window=window, softcap=softcap,
+        block_q=block_q, block_k=block_k, interpret=interpret)
+
+
+def _pallas_attention_fwd(q, k, v, scale, causal, window, softcap, block_q,
+                          block_k, interpret):
+    out, lse = flash_attention_kernel(
+        q, k, v, scale=scale, causal=causal, window=window, softcap=softcap,
+        block_q=block_q, block_k=block_k, interpret=interpret, with_lse=True)
+    return out, (q, k, v, out, lse)
+
+
+def _pallas_attention_bwd(scale, causal, window, softcap, block_q, block_k,
+                          interpret, res, d_out):
+    # flash_vjp works on the grouped (b, h, g, s, d) layout: view each of
+    # the BH rows as its own (h=1, g=1) head
+    q, k, v, out, lse = res
+    grouped = (q[:, None, None], k[:, None], v[:, None], out[:, None, None],
+               lse[:, None, None])
+    dq, dk, dv = _flash_bwd(scale, causal, window, softcap, 0, block_q,
+                            block_k, grouped, d_out[:, None, None])
+    return dq[:, 0, 0], dk[:, 0], dv[:, 0]
+
+
+_pallas_attention.defvjp(_pallas_attention_fwd, _pallas_attention_bwd)
 
 
 @functools.partial(
@@ -71,11 +109,9 @@ def multi_head_attention(
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     # padded kv columns must not attend: push them outside the causal frontier
     # by relying on causal mask when enabled; otherwise mask via big negative k
-    out = flash_attention_kernel(
+    out = _pallas_attention(
         qp.reshape(b * hq, sq + pad_q, d),
         kp.reshape(b * hq, skv + pad_k, d),
         vp.reshape(b * hq, skv + pad_k, d),
-        scale=scale, causal=causal, window=window, softcap=softcap,
-        block_q=blk_q, block_k=blk_k, interpret=interpret,
-    )
+        scale, causal, window, softcap, blk_q, blk_k, interpret)
     return out.reshape(b, hq, sq + pad_q, d)[:, :, :sq, :]

@@ -1,7 +1,8 @@
 """Jitted public wrapper for the OTA edge-aggregation kernel.
 
-Dispatches to the Pallas TPU kernel on TPU backends (interpret mode for CPU
-testing) and to the jnp oracle otherwise; pads N and d to tile boundaries.
+`impl='auto'` dispatches to the Pallas TPU kernel on TPU backends and to
+the jnp oracle otherwise; the Pallas interpreter runs only when a caller
+passes `interpret=True` (the CPU tests). Pads N and d to tile boundaries.
 
 `noise_scale` is a TRACED scalar operand: sweeping noise levels (or N,
 whose edge-noise std depends on it) reuses one compiled program per

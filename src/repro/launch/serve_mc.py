@@ -17,6 +17,10 @@ retried bit-identically, one transient quantum failure recovered under
 `McServeConfig.retry`, and one mid-run deadline expiry resolving with a
 `PartialResult` that matches a dedicated run over the completed seeds —
 all on a virtual clock, no wall-clock sleeps.
+
+`main(argv)` takes its arguments as a list and returns the exit code, so
+a caller in the same process (`chip_smoke.py`) runs the selftest as a
+function.
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ import asyncio
 import dataclasses
 import sys
 import time
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -36,6 +41,7 @@ from repro.core.mc import (
     run_mc,
     trace_count,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.mc_server import McServeConfig, SweepRequest, serve_sync
 
 
@@ -256,7 +262,9 @@ def _chaos(steps: int, seeds: int, quantum: int) -> int:
     return 0 if ok else 1
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    """Parse `argv` (None: the command line) and run; returns the exit
+    code (nonzero when a selftest check failed)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--seeds", type=int, default=8)
@@ -272,13 +280,13 @@ def main() -> None:
                     help="with --selftest: also run the scripted fault "
                          "scenarios (chunk retry, quantum retry, "
                          "deadline expiry)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.selftest:
         rc = _selftest(args.steps, args.seeds, args.quantum,
                        args.bucket_base)
         if args.chaos:
             rc |= _chaos(args.steps, args.seeds, args.quantum)
-        sys.exit(rc)
+        return rc
     reqs = _demo_requests(args.steps, args.seeds)
     clear_cache()
     t0 = time.time()
@@ -295,7 +303,9 @@ def main() -> None:
               f"n_max={b['n_max']} pad_flops_ratio={b['pad_flops_ratio']}")
     for i, res in enumerate(results):
         print(f"  request {i}: final mean risk {res.mean[:, -1]}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    enable_compile_cache()
+    sys.exit(main())

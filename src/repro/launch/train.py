@@ -1,6 +1,8 @@
 """Training launcher: `python -m repro.launch.train --arch repro-100m
 --steps 200 --aggregator gbma`. Runs on the local device(s); the production
-mesh path is exercised by dryrun.py (this container has one real CPU core).
+mesh path is exercised by dryrun.py. `main(argv)` takes its arguments as a
+list, so a caller in the same process (`chip_smoke.py`) drives it as a
+function and reads the returned loss history.
 
 `--aggregator` accepts EVERY algorithm in the MAC registry
 (`mc/slots.ALGO_REGISTRY`): gbma/fdm/centralized run the fused production
@@ -14,6 +16,7 @@ from __future__ import annotations
 
 import argparse
 import math
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,7 @@ from repro.core.channel import ChannelConfig
 from repro.core.gbma import GBMAConfig
 from repro.core.mc.slots import ALGO_REGISTRY
 from repro.data.synthetic import SyntheticTokens, TokenDatasetConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim.gd import get_optimizer
 from repro.training.loop import run_training
@@ -32,7 +36,9 @@ from repro.training.train_step import (TrainConfig, build_train_step,
                                        resolve_route)
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> list:
+    """Parse `argv` (None: the command line), train, and return the
+    per-step metric history of `run_training`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="repro-100m")
     ap.add_argument("--steps", type=int, default=200)
@@ -65,7 +71,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true",
                     help="use the smoke-test reduced config")
     ap.add_argument("--checkpoint", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -89,7 +95,7 @@ def main() -> None:
             power_budget=(args.power_budget if args.power_budget is not None
                           else math.inf),
             gamma=args.gamma, stepsize=args.lr, block_d=args.block_d,
-            transmit_dtype=args.transmit_dtype)
+            transmit_dtype=args.transmit_dtype, ota_impl="auto")
         if route == "transport" else None)
     opt = get_optimizer(args.optimizer, args.lr)
     step = build_train_step(model, tcfg, opt)
@@ -118,7 +124,9 @@ def main() -> None:
         print(f"saved checkpoint to {args.checkpoint}")
     print(f"final loss {hist[-1]['loss']:.4f} "
           f"(from {hist[0]['loss']:.4f})")
+    return hist
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

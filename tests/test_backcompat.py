@@ -33,25 +33,18 @@ PRIVATE_NAMES = [
     "_PER_NODE_FIELDS",
     "_ROW_FNS",
     "_antenna_keys",
-    "_bits_to_u01",
-    "_dynamic_bits",
-    "_dynamic_threefry_ok",
+    "_check_prefix_stable",
     "_magnitude_m2",
     "_mc_core",
-    "_normal_dynamic_n",
-    "_normal_padded",
+    "_node_mask",
     "_ota_slot",
     "_resolve_n_shards",
     "_row_complex_gains",
     "_row_gains",
+    "_row_normal",
     "_sample_complex_gains",
-    "_sample_complex_gains_dynamic_n",
-    "_sample_complex_gains_padded",
     "_sample_gains",
-    "_sample_gains_dynamic_n",
-    "_sample_gains_padded",
     "_sample_magnitude",
-    "_sample_magnitude_dynamic_n",
     "_slot_update",
 ]
 

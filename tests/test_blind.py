@@ -77,14 +77,15 @@ def test_blind_large_m_approaches_equal_gain_update():
     """Degeneracy (documented in docs/algorithms.md): with many antennas
     the blind MRC combine concentrates on the equal-gain GBMA update — the
     plain mean gradient at zero noise. Deviation is O(sqrt(N/(M m2)));
-    at N=8, M=4096 the fixed-seed relative L2 error is ~1%, asserted
-    at the documented 5% tolerance."""
+    at N=8, M=16384 the relative L2 error is ~2% (1.2-2.3% over seeds
+    0-7; at M=4096 it is ~4% and single seeds exceed 6%), asserted at
+    the documented 5% tolerance."""
     rng = np.random.default_rng(3)
     g = jnp.asarray(rng.standard_normal((8, 12)), jnp.float32)
     ch = _ch(noise_std=0.0)
     for seed in (0, 1):
         v = np.asarray(blind_ota_aggregate(g, jax.random.key(seed), ch,
-                                           4096))
+                                           16384))
         vc = np.asarray(jnp.mean(g, axis=0))
         rel = np.linalg.norm(v - vc) / np.linalg.norm(vc)
         assert rel < 0.05, f"seed {seed}: rel L2 {rel:.3f} >= 5%"
@@ -258,15 +259,14 @@ def test_complex_sampler_twin_matches_reference(fading, scale, rician_k,
        n=st.sampled_from([5, 8, 23, 32]),
        seed=st.integers(0, 2**16))
 def test_dynamic_complex_sampler_matches_shaped_draws(fading, n, seed):
-    """`_sample_complex_gains_dynamic_n` == the (n,)-shaped draw in lanes
-    [0, n), zero elsewhere — the blind family's N-sweep fast path."""
-    if not mc_mod._dynamic_threefry_ok():
-        pytest.skip("raw threefry primitive unavailable")
+    """`_row_complex_gains` on a padded sweep (the N_max draw, masked) ==
+    the (n,)-shaped draw in lanes [0, n), zero elsewhere — the blind
+    family's N-sweep draw."""
     p = {"scale": jnp.float32(0.9), "rician_k": jnp.float32(4.0),
          "n_nodes": jnp.float32(n)}
     key = jax.random.key(seed)
     ra, rb = mc_mod._sample_complex_gains(key, fading, p, (n,))
-    da, db = mc_mod._sample_complex_gains_dynamic_n(key, fading, p, 32)
+    da, db = mc_mod._row_complex_gains(key, fading, p, (n, 32), 32)
     for ref, dyn in ((ra, da), (rb, db)):
         # rounding (fma association) differences only; atol covers the
         # sin(phi)-near-zero lanes where rtol alone is meaningless
@@ -278,16 +278,10 @@ def test_dynamic_complex_sampler_matches_shaped_draws(fading, n, seed):
 @settings(max_examples=10, deadline=None)
 @given(m=st.sampled_from([1, 2, 5, 8]), seed=st.integers(0, 2**16))
 def test_antenna_key_replay_matches_split(m, seed):
-    """`_antenna_keys`' counts-as-data replay == `jax.random.split(key, m)`
+    """`_antenna_keys` (a split at the sweep's M_max) == `split(key, m)`
     in the first m lanes (the per-row M-sweep RNG discipline)."""
-    from repro import compat
-
-    if compat.threefry2x32 is None \
-            or not compat.threefry_split_is_original():
-        pytest.skip("original threefry split layout unavailable")
     key = jax.random.key(seed)
-    p = {"n_antennas": jnp.float32(m), "m_idx": jnp.int32(0)}
-    keys = mc_mod._antenna_keys(key, (1, 8), p)  # len > 1: dynamic path
+    keys = mc_mod._antenna_keys(key, (1, 8))
     ref = jax.random.key_data(jax.random.split(key, m))
     np.testing.assert_array_equal(
         np.asarray(jax.random.key_data(keys))[:m], np.asarray(ref))

@@ -17,6 +17,8 @@ without documenting/registering it fails tier-1.
 import pathlib
 import re
 
+import jax
+
 from repro import compat
 from repro.core.montecarlo import ALGOS, PROBLEMS
 
@@ -69,6 +71,19 @@ def test_compat_public_surface_is_documented():
         assert f"`{name}`" in text, (
             f"repro.compat.{name} is exported (__all__) but undocumented "
             "in docs/algorithms.md")
+
+
+def test_docs_claim_only_the_installed_jax():
+    """The repo supports the one JAX that requirements-ci.txt pins: the
+    pin is the installed version, and no doc claims another line."""
+    pin = re.search(r"^jax\S*==(\S+)$",
+                    (ROOT / "requirements-ci.txt").read_text(), re.M)
+    assert pin and pin.group(1) == jax.__version__
+    docs = [ROOT / "README.md", ROOT / ".claude/skills/verify/SKILL.md",
+            *sorted((ROOT / "docs").glob("*.md"))]
+    for doc in docs:
+        assert not re.search(r"\b0\.4\.(x|\d+)", doc.read_text()), (
+            f"{doc.name} still claims a JAX 0.4 line")
 
 
 def test_readme_doc_links_resolve():

@@ -60,10 +60,9 @@ def _row_params(fading, phase, m_row=None):
     p = {"scale": jnp.float32(0.9), "rician_k": jnp.float32(3.0),
          "phase_error_max": jnp.float32(phase),
          "noise_std": jnp.float32(0.5), "energy": jnp.float32(0.7),
-         "n_nodes": jnp.float32(N), "n_idx": jnp.int32(0)}
+         "n_nodes": jnp.float32(N)}
     if m_row is not None:
         p["n_antennas"] = jnp.float32(m_row)
-        p["m_idx"] = jnp.int32(0)
     return p
 
 
@@ -107,8 +106,7 @@ def test_gbma_hoist_streams_bit_identical_to_inscan(fading, phase, mode,
         elif mode == "static_m":
             akeys = list(jax.random.split(step_keys[t], 3))
         else:
-            akeys = list(sampling._antenna_keys(step_keys[t], (2, 4),
-                                                ctx.p))
+            akeys = list(sampling._antenna_keys(step_keys[t], (2, 4)))
         for a_i, ak in enumerate(akeys):
             h, w = _inscan_ota_draw(ak, ctx)
             got_h = draws.get("h")
@@ -156,8 +154,7 @@ def test_fdm_and_pc_hoist_streams_bit_identical_to_inscan(fading, invert,
     pc = ALGO_REGISTRY["power_control"].hoist_draws(step_keys, ctx, N, D)
     for t in range(4):
         k_h, k_w = jax.random.split(step_keys[t])
-        raw = sampling._normal_padded(k_w, ctx.p["n_idx"], (N,), N, D,
-                                      jnp.float32)
+        raw = sampling._row_normal(k_w, ctx.p, (N,), N, D, jnp.float32)
         np.testing.assert_array_equal(np.asarray(fdm["noise_raw"][t]),
                                       np.asarray(raw))
         if not invert and not (fading == "equal" and ctx.phase_zero):

@@ -367,8 +367,14 @@ class TestParticipation:
         single = run_mc(*args, participation=0.7)
         chunked = run_mc(*args, participation=0.7,
                          plan=ExecPlan(seed_chunk=2))
-        np.testing.assert_array_equal(chunked.risks, single.risks)
-        np.testing.assert_array_equal(chunked.mean, single.mean)
+        # the dropout stream is counter-based, so chunks draw the same
+        # masks; XLA rounds a 2-seed vmap and a 6-seed vmap differently
+        # in the last ulp (with or without participation), hence the
+        # same bound as the chunked-vs-full test of test_exec.py
+        np.testing.assert_allclose(chunked.risks, single.risks, rtol=1e-6,
+                                   atol=1e-10)
+        np.testing.assert_allclose(chunked.mean, single.mean, rtol=1e-6,
+                                   atol=1e-10)
 
     def test_memory_model_counts_the_mask_stream(self):
         base = exec_mod.estimate_peak_bytes(

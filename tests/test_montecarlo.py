@@ -375,15 +375,15 @@ def test_ota_impl_ref_parity(prob, mc):
 
 
 def test_ota_impl_pallas_parity_interpret(prob, mc):
-    """The Pallas kernel path (interpret mode off-TPU) matches inline —
-    the ROADMAP 'pallas path for the per-slot aggregation' item. Short
+    """The Pallas kernel path, run in the Pallas interpreter (asked for
+    explicitly: 'pallas' itself is TPU-only), matches inline. Short
     horizon: interpret mode is slow."""
     ch = _ch()
     beta = stepsize_theorem1(prob.pc, ch, N, safety=0.8)
     steps = 5
     r_inline = run_mc(mc, [ch], "gbma", [beta], steps, 1)
     r_pallas = run_mc(mc, [ch], "gbma", [beta], steps, 1,
-                      ota_impl="pallas")
+                      ota_impl="interpret")
     np.testing.assert_allclose(r_pallas.risks, r_inline.risks, rtol=1e-5,
                                atol=1e-9)
 
@@ -428,26 +428,22 @@ def test_sample_gains_twin_matches_reference(fading, scale, phase, rician_k,
        n=st.sampled_from([5, 8, 23, 31, 32]),
        seed=st.integers(0, 2**16))
 def test_dynamic_n_sampler_matches_shaped_draws(fading, n, seed):
-    """`_sample_gains_dynamic_n` (static-shape counts-as-data threefry)
-    reproduces the (n,)-shaped draw in lanes [0, n) — to float rounding
-    (fused-multiply-add differences only) — and zero-pads the rest."""
-    from repro import compat
-
-    if compat.threefry2x32 is None or not compat.threefry_is_default():
-        pytest.skip("raw threefry primitive unavailable")
+    """`_row_gains` on a padded sweep (the N_max draw, masked) reproduces
+    the (n,)-shaped draw in lanes [0, n) — to float rounding — and zeroes
+    the rest: prefix-stable threefry draws across every fading family."""
     p = {"scale": jnp.float32(0.9), "rician_k": jnp.float32(4.0),
          "phase_error_max": jnp.float32(0.4), "n_nodes": jnp.float32(n)}
     key = jax.random.key(seed)
     ref = mc_mod._sample_gains(key, fading, p, (n,))
-    dyn = mc_mod._sample_gains_dynamic_n(key, fading, p, 32)
+    dyn = mc_mod._row_gains(key, fading, p, (n, 32), 32)
     np.testing.assert_allclose(np.asarray(dyn[:n]), np.asarray(ref),
                                rtol=5e-7, atol=0)
     assert np.all(np.asarray(dyn[n:]) == 0.0)
 
 
 def test_nsweep_fdm_matches_per_n():
-    """fdm node-count sweeps (the per-node noise draw is shape-dependent
-    too, handled by `_normal_dynamic_n`) reproduce the per-N runs."""
+    """fdm node-count sweeps (the per-node noise is a masked N_max draw,
+    `_row_normal`) reproduce the per-N runs."""
     probs = [MSDProblem.make(n, dim=12) for n in (10, 17)]
     chs = [_ch() for _ in probs]
     mcs = [p.to_mc() for p in probs]
@@ -465,13 +461,12 @@ def test_nsweep_fdm_matches_per_n():
 @given(n=st.sampled_from([5, 8, 13]), d=st.sampled_from([3, 7]),
        seed=st.integers(0, 2**16))
 def test_dynamic_normal_matches_shaped_draws(n, d, seed):
-    from repro import compat
-
-    if compat.threefry2x32 is None or not compat.threefry_is_default():
-        pytest.skip("raw threefry primitive unavailable")
+    """`_row_normal` (the fdm per-node noise on a padded sweep) == the
+    (n, d)-shaped draw in its first n rows, zero below."""
     key = jax.random.key(seed)
     ref = jax.random.normal(key, (n, d))
-    dyn = mc_mod._normal_dynamic_n(key, jnp.int32(n), 16, d)
+    p = {"n_nodes": jnp.float32(n)}
+    dyn = mc_mod._row_normal(key, p, (n, 16), 16, d, jnp.float32)
     np.testing.assert_allclose(np.asarray(dyn[:n]), np.asarray(ref),
                                rtol=5e-7, atol=1e-7)
     assert np.all(np.asarray(dyn[n:]) == 0.0)
