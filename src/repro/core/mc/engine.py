@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.channel import ChannelConfig
 from repro.core.mc import exec as exec_mod
 from repro.core.mc.exec import (  # noqa: F401  (re-exported surface)
@@ -60,6 +61,11 @@ from repro.core.mc.slots import ALGO_REGISTRY, resolve_ota_impl
 from repro.core.theory import ProblemConstants, theorem1_bound
 
 Array = jax.Array
+
+# node-slots of every dispatched batch: sum over rows of N_i, and rows x
+# N_max as padded (the `repro.run_mc.dispatch` span's stats, summed)
+_NODE_SLOTS = obs.Counter("repro.run_mc.node_slots")
+_PADDED_NODE_SLOTS = obs.Counter("repro.run_mc.padded_node_slots")
 
 
 # --------------------------------------------------------------------------
@@ -293,256 +299,274 @@ def run_mc(
     statically disables the stream and is bit-identical to a run without
     the knob.
     """
-    ch_batch = channels if isinstance(channels, ChannelBatch) \
-        else ChannelBatch.stack(list(channels))
-    n_rows = len(ch_batch)
-    betas = jnp.asarray(betas, jnp.float32)
-    if betas.shape != (n_rows,):
-        raise ValueError(f"need one stepsize per row: "
-                         f"{betas.shape} vs C={n_rows}")
-    algos = (algo,) * n_rows if isinstance(algo, str) else tuple(algo)
-    if len(algos) != n_rows:
-        raise ValueError(f"need one algo per row: {len(algos)} vs C={n_rows}")
-    for a in algos:
-        if a not in ALGO_REGISTRY:
-            raise ValueError(f"unknown algo {a!r}; expected one of "
-                             f"{tuple(ALGO_REGISTRY)}")
-    specs = [ALGO_REGISTRY[a] for a in algos]
-    if rng_plan is not None and rng_plan not in ("hoisted", "inscan"):
-        raise ValueError(
-            f"rng_plan must be 'hoisted' or 'inscan', got {rng_plan!r}")
-    if plan is not None:
-        clash = [name for name, v in (
-            ("rng_plan", rng_plan), ("seed_chunk", seed_chunk),
-            ("keep_seed_curves", keep_seed_curves),
-            ("ota_impl", ota_impl), ("shard_seeds", shard_seeds))
-            if v is not None]
-        if clash:
+    with obs.CallSpans("repro.run_mc", rows=len(channels), seeds=seeds,
+                       steps=steps) as spans:
+        spans.phase("prepare")
+        ch_batch = channels if isinstance(channels, ChannelBatch) \
+            else ChannelBatch.stack(list(channels))
+        n_rows = len(ch_batch)
+        betas = jnp.asarray(betas, jnp.float32)
+        if betas.shape != (n_rows,):
+            raise ValueError(f"need one stepsize per row: "
+                             f"{betas.shape} vs C={n_rows}")
+        algos = (algo,) * n_rows if isinstance(algo, str) else tuple(algo)
+        if len(algos) != n_rows:
             raise ValueError(
-                f"plan= already pins the execution strategy; drop the "
-                f"conflicting legacy knob(s) {clash} or encode them in "
-                "the ExecPlan")
-        if isinstance(plan, str) and plan != "auto":
+                f"need one algo per row: {len(algos)} vs C={n_rows}")
+        for a in algos:
+            if a not in ALGO_REGISTRY:
+                raise ValueError(f"unknown algo {a!r}; expected one of "
+                                 f"{tuple(ALGO_REGISTRY)}")
+        specs = [ALGO_REGISTRY[a] for a in algos]
+        if rng_plan is not None and rng_plan not in ("hoisted", "inscan"):
             raise ValueError(
-                f"plan must be an ExecPlan or the string 'auto', "
-                f"got {plan!r}")
-    if memory_budget_bytes is not None and plan != "auto":
-        raise ValueError(
-            "memory_budget_bytes only parameterizes plan='auto' — an "
-            "explicit ExecPlan or the legacy knobs already fix the chunk "
-            "size")
-
-    # ---- normalize the antenna axis ------------------------------------
-    if n_antennas is None or isinstance(n_antennas, (int, np.integer)):
-        if n_antennas is not None:
-            n_antennas = int(n_antennas)
-        m_per_row, m_sizes = None, ()
-    else:
-        m_per_row = tuple(int(m) for m in n_antennas)
-        if len(m_per_row) != n_rows:
-            raise ValueError(f"need one antenna count per row: "
-                             f"{len(m_per_row)} vs C={n_rows}")
-        if any(m < 1 for m in m_per_row):
-            raise ValueError(f"antenna counts must be >= 1: {m_per_row}")
-        m_sizes = tuple(sorted(set(m_per_row)))
-        n_antennas = None  # the static broadcast arg is off in per-row mode
-    if any(s.blind for s in specs) and n_antennas is None and not m_sizes:
-        raise ValueError(
-            "blind/blind_ec need n_antennas (the edge antenna count M)")
-
-    # ---- normalize the problem axis ------------------------------------
-    if isinstance(problem, MCProblemBatch):
-        batch_prob = problem
-    elif isinstance(problem, MCProblem):
-        batch_prob = None  # closure path: one problem shared by all rows
-    else:
-        probs = list(problem)
-        if len(probs) == 1:
-            batch_prob = None
-            problem = probs[0]
-        else:
-            if len(probs) != n_rows:
+                f"rng_plan must be 'hoisted' or 'inscan', got {rng_plan!r}")
+        if plan is not None:
+            clash = [name for name, v in (
+                ("rng_plan", rng_plan), ("seed_chunk", seed_chunk),
+                ("keep_seed_curves", keep_seed_curves),
+                ("ota_impl", ota_impl), ("shard_seeds", shard_seeds))
+                if v is not None]
+            if clash:
                 raise ValueError(
-                    f"need one problem per row: {len(probs)} vs C={n_rows}")
-            batch_prob = MCProblemBatch.stack(probs)
+                    f"plan= already pins the execution strategy; drop the "
+                    f"conflicting legacy knob(s) {clash} or encode them in "
+                    "the ExecPlan")
+            if isinstance(plan, str) and plan != "auto":
+                raise ValueError(
+                    f"plan must be an ExecPlan or the string 'auto', "
+                    f"got {plan!r}")
+        if memory_budget_bytes is not None and plan != "auto":
+            raise ValueError(
+                "memory_budget_bytes only parameterizes plan='auto' — an "
+                "explicit ExecPlan or the legacy knobs already fix the chunk "
+                "size")
 
-    # stochastic minibatching needs the row-based data path; lift a single
-    # broadcast problem into a C-row batch (cheap: data is small)
-    sto_spec, b_max, b_counts = _resolve_batch_frac(
-        batch_frac, n_rows, batch_prob, problem)
-    sgrad_fn = sto_spec.stochastic_grad_row if sto_spec is not None else None
-    if sgrad_fn is not None and batch_prob is None:
-        batch_prob = MCProblemBatch.stack([problem] * n_rows)
-
-    if batch_prob is not None:
-        row_based = True
-        grad_fn, risk_fn = batch_prob.grad_fn, batch_prob.risk_fn
-        data = dict(batch_prob.data)
-        n_nodes = batch_prob.n_nodes
-        dim, n_max = batch_prob.dim, batch_prob.n_max
-    else:
-        row_based = False
-        grad_fn, risk_fn = problem.grad_fn, problem.risk_fn
-        n_nodes = (problem.n_nodes,) * n_rows
-        dim, n_max = problem.dim, problem.n_nodes
-        data = {"mask": jnp.ones((n_rows, n_max), jnp.float32)}
-
-    n_sizes = tuple(sorted(set(n_nodes)))
-    algo_set = tuple(dict.fromkeys(algos))
-
-    # ---- normalize node participation ----------------------------------
-    if isinstance(participation, (int, float, np.integer, np.floating)):
-        parts = (float(participation),) * n_rows
-    else:
-        parts = tuple(float(p) for p in participation)
-        if len(parts) != n_rows:
-            raise ValueError(f"need one participation per row: "
-                             f"{len(parts)} vs C={n_rows}")
-    if any(not (0.0 < p <= 1.0) for p in parts):
-        raise ValueError(f"participation must be in (0, 1], got {parts}")
-    # static on/off only — the probabilities themselves are data, so a
-    # per-row p sweep shares one compile; p = 1.0 everywhere disables the
-    # mask stream entirely (bit-identical to a run without the knob, and
-    # params stays key-identical so resume fingerprints don't shift)
-    participation_on = any(p < 1.0 for p in parts)
-
-    # ---- resolve the execution plan ------------------------------------
-    # Three sources, one record: an explicit ExecPlan, "auto" (derived
-    # from the memory model + topology), or the legacy kwargs building
-    # the equivalent plan. The legacy shim is behavior-pinned: every
-    # sentinel (None) maps to the exact pre-plan default, and
-    # shard_seeds=True resolves through the legacy rule (including its
-    # divisibility error) before the plan is built.
-    if isinstance(plan, ExecPlan):
-        eff_plan = plan
-    elif plan == "auto":
-        eff_plan = auto_plan(
-            n_rows=n_rows, seeds=seeds, steps=steps, n_max=n_max, dim=dim,
-            algo_set=algo_set, n_antennas=n_antennas, m_sizes=m_sizes,
-            b_max=b_max, invert_channel=invert_channel,
-            participation_on=participation_on,
-            memory_budget_bytes=memory_budget_bytes)
-    else:
-        shim_shards: Optional[int] = None
-        if shard_seeds is False:
-            shim_shards = 0
-        elif shard_seeds is True:
-            shim_shards = _resolve_n_shards(
-                seed_chunk if seed_chunk is not None else seeds, True)
-        eff_plan = ExecPlan(
-            rng_plan="hoisted" if rng_plan is None else rng_plan,
-            seed_chunk=seed_chunk,
-            n_shards=shim_shards,
-            row_shards=1,
-            keep_seed_curves=(True if keep_seed_curves is None
-                              else keep_seed_curves),
-            ota_impl="auto" if ota_impl is None else ota_impl)
-    validate_plan(eff_plan, seeds=seeds, n_rows=n_rows)
-    n_shards = resolve_seed_shards(eff_plan, seeds)
-    if resume_dir is not None and (eff_plan.seed_chunk is None
-                                   or eff_plan.keep_seed_curves):
-        raise ValueError(
-            "resume_dir requires a chunked reduced sweep — a plan with "
-            "seed_chunk set and keep_seed_curves=False (only the chunk "
-            "cursor and moment accumulators are checkpointed)")
-
-    ota_resolved = resolve_ota_impl(eff_plan.ota_impl, n_sizes)
-    # static promise for the hoisted plan's phase-stream shortcut: every
-    # row's phase draw is over [-0, 0] (cos(0)=1, value-identical to
-    # skip). Only hoist-eligible calls (hoisted plan, one algorithm WITH
-    # a hoist twin) set it — elsewhere nothing reads it, and a static
-    # True/False split would needlessly fragment the jit cache across
-    # phase settings that the legacy body treats as pure data.
-    phase_zero = (
-        eff_plan.rng_plan == "hoisted" and len(algo_set) == 1
-        and ALGO_REGISTRY[algo_set[0]].hoist_draws is not None
-        and all(float(c.phase_error_max) == 0.0
-                for c in ch_batch.configs))
-    params = dict(ch_batch.params)
-    params["n_nodes"] = jnp.asarray(n_nodes, jnp.float32)
-    params["algo_idx"] = jnp.asarray(
-        [algo_set.index(a) for a in algos], jnp.int32)
-    params["gamma"] = jnp.asarray(
-        [momentum if s.uses_gamma else 0.0 for s in specs], jnp.float32)
-    params["nest"] = jnp.asarray(
-        [1.0 if s.nesterov else 0.0 for s in specs], jnp.float32)
-    params["ec"] = jnp.asarray(
-        [1.0 if s.error_feedback else 0.0 for s in specs], jnp.float32)
-    if power_budget is None:
-        budgets = (float("inf"),) * n_rows
-    elif isinstance(power_budget, (int, float, np.integer, np.floating)):
-        budgets = (float(power_budget),) * n_rows
-    else:
-        budgets = tuple(float(b) for b in power_budget)
-        if len(budgets) != n_rows:
-            raise ValueError(f"need one power budget per row: "
-                             f"{len(budgets)} vs C={n_rows}")
-    params["tx_budget"] = jnp.asarray(budgets, jnp.float32)
-    if m_sizes:
-        params["n_antennas"] = jnp.asarray(m_per_row, jnp.float32)
-    if b_counts is not None:
-        # int32, NOT float32: a lane count is integral and must survive
-        # exactly (float32 rounds above 2^24); the single consumer divides
-        # by it after an explicit float cast
-        params["b_count"] = jnp.asarray(b_counts, jnp.int32)
-    if participation_on:
-        params["participation"] = jnp.asarray(parts, jnp.float32)
-
-    t0 = jnp.zeros((dim,), jnp.float32) if theta0 is None \
-        else jnp.asarray(theta0, jnp.float32)
-    seed_ints = np.arange(seed0, seed0 + seeds, dtype=np.int32)
-    core_kwargs = dict(
-        grad_fn=grad_fn, risk_fn=risk_fn, row_based=row_based,
-        algo_set=algo_set, fading=ch_batch.fading, steps=steps,
-        n_sizes=n_sizes, n_antennas=n_antennas, m_sizes=m_sizes,
-        invert_channel=invert_channel, h_min=h_min,
-        sgrad_fn=sgrad_fn, b_max=b_max, ota_impl=ota_resolved,
-        rng_plan=eff_plan.rng_plan, phase_zero=phase_zero,
-        sample_idx_fn=(sto_spec.sample_indices_row
-                       if sto_spec is not None else None),
-        sgrad_idx_fn=(sto_spec.stochastic_grad_from_idx
-                      if sto_spec is not None else None),
-        participation_on=participation_on)
-    if eff_plan.seed_chunk is not None:
-        risks, cum_e, mean, ci95 = exec_mod.run_chunked(
-            params, betas, t0, seed_ints, data,
-            seed_chunk=eff_plan.seed_chunk,
-            keep_seed_curves=eff_plan.keep_seed_curves,
-            n_shards=n_shards, row_shards=eff_plan.row_shards,
-            core_kwargs=core_kwargs, resume_dir=resume_dir,
-            retry=eff_plan.retry)
-    else:
-        seed_arr = jnp.asarray(seed_ints)
-        if eff_plan.keep_seed_curves:
-            risks, cum_e = _mc_core(params, betas, t0, seed_arr, data,
-                                    n_shards=n_shards,
-                                    row_shards=eff_plan.row_shards,
-                                    **core_kwargs)
-            risks, cum_e = np.asarray(risks), np.asarray(cum_e)
-            mean, ci95 = exec_mod.host_seed_stats(risks)
+        # ---- normalize the antenna axis ------------------------------------
+        if n_antennas is None or isinstance(n_antennas, (int, np.integer)):
+            if n_antennas is not None:
+                n_antennas = int(n_antennas)
+            m_per_row, m_sizes = None, ()
         else:
-            mean, ci95 = exec_mod._mc_stats(
-                params, betas, t0, seed_arr, data, n_shards=n_shards,
-                row_shards=eff_plan.row_shards, **core_kwargs)
-            mean, ci95 = np.asarray(mean), np.asarray(ci95)
-            risks = cum_e = None
-    bounds = None
-    if pc is not None:
-        pcs = [pc] * n_rows if isinstance(pc, ProblemConstants) else list(pc)
-        if len(pcs) != n_rows:
-            raise ValueError(f"need one ProblemConstants per row: "
-                             f"{len(pcs)} vs C={n_rows}")
-        if all(s.theorem1 for s in specs) and n_antennas is None \
-                and not m_sizes:
-            ks = np.arange(1, steps + 2)
-            bounds = np.stack([
-                theorem1_bound(ks, float(b), row_pc, cfg, n)
-                for b, cfg, row_pc, n in zip(
-                    np.asarray(betas), ch_batch.configs, pcs, n_nodes)])
-    return MCResult(
-        risks=risks, mean=mean.astype(np.float32),
-        ci95=ci95.astype(np.float32), cum_energy=cum_e,
-        bounds=bounds, plan=eff_plan)
+            m_per_row = tuple(int(m) for m in n_antennas)
+            if len(m_per_row) != n_rows:
+                raise ValueError(f"need one antenna count per row: "
+                                 f"{len(m_per_row)} vs C={n_rows}")
+            if any(m < 1 for m in m_per_row):
+                raise ValueError(f"antenna counts must be >= 1: {m_per_row}")
+            m_sizes = tuple(sorted(set(m_per_row)))
+            # the static broadcast arg is off in per-row mode
+            n_antennas = None
+        if any(s.blind for s in specs) and n_antennas is None and not m_sizes:
+            raise ValueError(
+                "blind/blind_ec need n_antennas (the edge antenna count M)")
+
+        # ---- normalize the problem axis ------------------------------------
+        if isinstance(problem, MCProblemBatch):
+            batch_prob = problem
+        elif isinstance(problem, MCProblem):
+            batch_prob = None  # closure path: one problem shared by all rows
+        else:
+            probs = list(problem)
+            if len(probs) == 1:
+                batch_prob = None
+                problem = probs[0]
+            else:
+                if len(probs) != n_rows:
+                    raise ValueError(
+                        f"need one problem per row: {len(probs)} vs "
+                        f"C={n_rows}")
+                batch_prob = MCProblemBatch.stack(probs)
+
+        # stochastic minibatching needs the row-based data path; lift a single
+        # broadcast problem into a C-row batch (cheap: data is small)
+        sto_spec, b_max, b_counts = _resolve_batch_frac(
+            batch_frac, n_rows, batch_prob, problem)
+        sgrad_fn = sto_spec.stochastic_grad_row if sto_spec is not None \
+            else None
+        if sgrad_fn is not None and batch_prob is None:
+            batch_prob = MCProblemBatch.stack([problem] * n_rows)
+
+        if batch_prob is not None:
+            row_based = True
+            grad_fn, risk_fn = batch_prob.grad_fn, batch_prob.risk_fn
+            data = dict(batch_prob.data)
+            n_nodes = batch_prob.n_nodes
+            dim, n_max = batch_prob.dim, batch_prob.n_max
+        else:
+            row_based = False
+            grad_fn, risk_fn = problem.grad_fn, problem.risk_fn
+            n_nodes = (problem.n_nodes,) * n_rows
+            dim, n_max = problem.dim, problem.n_nodes
+            data = {"mask": jnp.ones((n_rows, n_max), jnp.float32)}
+
+        n_sizes = tuple(sorted(set(n_nodes)))
+        algo_set = tuple(dict.fromkeys(algos))
+
+        # ---- normalize node participation ----------------------------------
+        if isinstance(participation, (int, float, np.integer, np.floating)):
+            parts = (float(participation),) * n_rows
+        else:
+            parts = tuple(float(p) for p in participation)
+            if len(parts) != n_rows:
+                raise ValueError(f"need one participation per row: "
+                                 f"{len(parts)} vs C={n_rows}")
+        if any(not (0.0 < p <= 1.0) for p in parts):
+            raise ValueError(f"participation must be in (0, 1], got {parts}")
+        # static on/off only — the probabilities themselves are data, so a
+        # per-row p sweep shares one compile; p = 1.0 everywhere disables the
+        # mask stream entirely (bit-identical to a run without the knob, and
+        # params stays key-identical so resume fingerprints don't shift)
+        participation_on = any(p < 1.0 for p in parts)
+
+        params = dict(ch_batch.params)
+        params["n_nodes"] = jnp.asarray(n_nodes, jnp.float32)
+        params["algo_idx"] = jnp.asarray(
+            [algo_set.index(a) for a in algos], jnp.int32)
+        params["gamma"] = jnp.asarray(
+            [momentum if s.uses_gamma else 0.0 for s in specs], jnp.float32)
+        params["nest"] = jnp.asarray(
+            [1.0 if s.nesterov else 0.0 for s in specs], jnp.float32)
+        params["ec"] = jnp.asarray(
+            [1.0 if s.error_feedback else 0.0 for s in specs], jnp.float32)
+        if power_budget is None:
+            budgets = (float("inf"),) * n_rows
+        elif isinstance(power_budget, (int, float, np.integer, np.floating)):
+            budgets = (float(power_budget),) * n_rows
+        else:
+            budgets = tuple(float(b) for b in power_budget)
+            if len(budgets) != n_rows:
+                raise ValueError(f"need one power budget per row: "
+                                 f"{len(budgets)} vs C={n_rows}")
+        params["tx_budget"] = jnp.asarray(budgets, jnp.float32)
+        if m_sizes:
+            params["n_antennas"] = jnp.asarray(m_per_row, jnp.float32)
+        if b_counts is not None:
+            # int32, NOT float32: a lane count is integral and must survive
+            # exactly (float32 rounds above 2^24); the single consumer divides
+            # by it after an explicit float cast
+            params["b_count"] = jnp.asarray(b_counts, jnp.int32)
+        if participation_on:
+            params["participation"] = jnp.asarray(parts, jnp.float32)
+
+        t0 = jnp.zeros((dim,), jnp.float32) if theta0 is None \
+            else jnp.asarray(theta0, jnp.float32)
+        seed_ints = np.arange(seed0, seed0 + seeds, dtype=np.int32)
+
+        spans.phase("plan")
+        # ---- resolve the execution plan ------------------------------------
+        # Three sources, one record: an explicit ExecPlan, "auto" (derived
+        # from the memory model + topology), or the legacy kwargs building
+        # the equivalent plan. The legacy shim is behavior-pinned: every
+        # sentinel (None) maps to the exact pre-plan default, and
+        # shard_seeds=True resolves through the legacy rule (including its
+        # divisibility error) before the plan is built.
+        if isinstance(plan, ExecPlan):
+            eff_plan = plan
+        elif plan == "auto":
+            eff_plan = auto_plan(
+                n_rows=n_rows, seeds=seeds, steps=steps, n_max=n_max, dim=dim,
+                algo_set=algo_set, n_antennas=n_antennas, m_sizes=m_sizes,
+                b_max=b_max, invert_channel=invert_channel,
+                participation_on=participation_on,
+                memory_budget_bytes=memory_budget_bytes)
+        else:
+            shim_shards: Optional[int] = None
+            if shard_seeds is False:
+                shim_shards = 0
+            elif shard_seeds is True:
+                shim_shards = _resolve_n_shards(
+                    seed_chunk if seed_chunk is not None else seeds, True)
+            eff_plan = ExecPlan(
+                rng_plan="hoisted" if rng_plan is None else rng_plan,
+                seed_chunk=seed_chunk,
+                n_shards=shim_shards,
+                row_shards=1,
+                keep_seed_curves=(True if keep_seed_curves is None
+                                  else keep_seed_curves),
+                ota_impl="auto" if ota_impl is None else ota_impl)
+        validate_plan(eff_plan, seeds=seeds, n_rows=n_rows)
+        n_shards = resolve_seed_shards(eff_plan, seeds)
+        if resume_dir is not None and (eff_plan.seed_chunk is None
+                                       or eff_plan.keep_seed_curves):
+            raise ValueError(
+                "resume_dir requires a chunked reduced sweep — a plan with "
+                "seed_chunk set and keep_seed_curves=False (only the chunk "
+                "cursor and moment accumulators are checkpointed)")
+
+        ota_resolved = resolve_ota_impl(eff_plan.ota_impl, n_sizes)
+        # static promise for the hoisted plan's phase-stream shortcut: every
+        # row's phase draw is over [-0, 0] (cos(0)=1, value-identical to
+        # skip). Only hoist-eligible calls (hoisted plan, one algorithm WITH
+        # a hoist twin) set it — elsewhere nothing reads it, and a static
+        # True/False split would needlessly fragment the jit cache across
+        # phase settings that the legacy body treats as pure data.
+        phase_zero = (
+            eff_plan.rng_plan == "hoisted" and len(algo_set) == 1
+            and ALGO_REGISTRY[algo_set[0]].hoist_draws is not None
+            and all(float(c.phase_error_max) == 0.0
+                    for c in ch_batch.configs))
+        core_kwargs = dict(
+            grad_fn=grad_fn, risk_fn=risk_fn, row_based=row_based,
+            algo_set=algo_set, fading=ch_batch.fading, steps=steps,
+            n_sizes=n_sizes, n_antennas=n_antennas, m_sizes=m_sizes,
+            invert_channel=invert_channel, h_min=h_min,
+            sgrad_fn=sgrad_fn, b_max=b_max, ota_impl=ota_resolved,
+            rng_plan=eff_plan.rng_plan, phase_zero=phase_zero,
+            sample_idx_fn=(sto_spec.sample_indices_row
+                           if sto_spec is not None else None),
+            sgrad_idx_fn=(sto_spec.stochastic_grad_from_idx
+                          if sto_spec is not None else None),
+            participation_on=participation_on)
+
+        spans.phase("dispatch", node_slots=sum(n_nodes),
+                    padded_node_slots=n_rows * n_max,
+                    trajectories=n_rows * seeds)
+        _NODE_SLOTS.add(sum(n_nodes))
+        _PADDED_NODE_SLOTS.add(n_rows * n_max)
+        if eff_plan.seed_chunk is not None:
+            risks, cum_e, mean, ci95 = exec_mod.run_chunked(
+                params, betas, t0, seed_ints, data,
+                seed_chunk=eff_plan.seed_chunk,
+                keep_seed_curves=eff_plan.keep_seed_curves,
+                n_shards=n_shards, row_shards=eff_plan.row_shards,
+                core_kwargs=core_kwargs, resume_dir=resume_dir,
+                retry=eff_plan.retry, call=spans.call)
+        else:
+            core = _mc_core if eff_plan.keep_seed_curves \
+                else exec_mod._mc_stats
+            out = core(params, betas, t0, jnp.asarray(seed_ints), data,
+                       n_shards=n_shards, row_shards=eff_plan.row_shards,
+                       **core_kwargs)
+            spans.phase("fetch")
+            out = tuple(np.asarray(a) for a in out)
+            if eff_plan.keep_seed_curves:
+                risks, cum_e = out
+                spans.phase("stats")
+                mean, ci95 = exec_mod.host_seed_stats(risks)
+            else:
+                mean, ci95 = out
+                risks = cum_e = None
+        spans.end_phase()
+        bounds = None
+        if pc is not None:
+            pcs = [pc] * n_rows if isinstance(pc, ProblemConstants) \
+                else list(pc)
+            if len(pcs) != n_rows:
+                raise ValueError(f"need one ProblemConstants per row: "
+                                 f"{len(pcs)} vs C={n_rows}")
+            if all(s.theorem1 for s in specs) and n_antennas is None \
+                    and not m_sizes:
+                spans.phase("bounds")
+                ks = np.arange(1, steps + 2)
+                bounds = np.stack([
+                    theorem1_bound(ks, float(b), row_pc, cfg, n)
+                    for b, cfg, row_pc, n in zip(
+                        np.asarray(betas), ch_batch.configs, pcs, n_nodes)])
+        return MCResult(
+            risks=risks, mean=mean.astype(np.float32),
+            ci95=ci95.astype(np.float32), cum_energy=cum_e,
+            bounds=bounds, plan=eff_plan)
 
 
 def slice_result(res: MCResult, rows: Union[slice, Sequence[int]]) -> MCResult:

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.checkpoint import ckpt
 from repro.core.mc.slots import ALGO_REGISTRY, SlotCtx
 
@@ -83,7 +83,7 @@ _DATA_STREAM = 0x64617461  # b"data"
 # dropout cannot shift any other draw
 _PART_STREAM = 0x70617274  # b"part"
 
-_TRACE_COUNT = 0
+_TRACES = obs.Counter()  # engine traces (== XLA compiles)
 _CACHE_EPOCH = 0
 
 
@@ -101,11 +101,7 @@ def trace_count(reset: bool = False) -> int:
     the last reset. `reset=True` returns the current count and zeroes it;
     `clear_cache()` also zeroes it, so compile-count tests can write
     `clear_cache(); ...; assert trace_count() == 1`."""
-    global _TRACE_COUNT
-    count = _TRACE_COUNT
-    if reset:
-        _TRACE_COUNT = 0
-    return count
+    return _TRACES.value(reset)
 
 
 def clear_cache() -> bool:
@@ -113,8 +109,8 @@ def clear_cache() -> bool:
     cold benchmark timings) and reset the trace counter. Returns False on
     JAX versions without jit clear_cache support — callers should then
     skip compile-count asserts."""
-    global _TRACE_COUNT, _CACHE_EPOCH
-    _TRACE_COUNT = 0
+    global _CACHE_EPOCH
+    _TRACES.value(reset=True)
     _CACHE_EPOCH += 1
     cleared = False
     for fn in (_mc_core, _mc_stats, _mc_moments_merge):
@@ -203,8 +199,7 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
     multi-group merge under `lax.psum`, so only (C, steps+1) statistics
     cross device boundaries regardless of placement.
     """
-    global _TRACE_COUNT
-    _TRACE_COUNT += 1  # python side effect: runs once per trace/compile
+    _TRACES.add()  # python side effect: runs once per trace/compile
 
     use_ec = any(ALGO_REGISTRY[a].error_feedback for a in algo_set)
     # The hoisted plan applies to HOMOGENEOUS calls only: with several
@@ -252,15 +247,19 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
             else:
                 theta, m, cum_e = carry
             theta_eval = theta - p["nest"] * beta * p["gamma"] * m
-            if sgrad_fn is not None:
-                if idx is not None:
-                    g = sgrad_idx_fn(row, theta_eval, idx, p["b_count"])
+            with jax.named_scope("repro.mc.grad"):
+                if sgrad_fn is not None:
+                    if idx is not None:
+                        g = sgrad_idx_fn(row, theta_eval, idx,
+                                         p["b_count"])
+                    else:
+                        g = sgrad_fn(row, theta_eval, dk, p["b_count"],
+                                     b_max)
                 else:
-                    g = sgrad_fn(row, theta_eval, dk, p["b_count"], b_max)
-            else:
-                g = (grad_fn(row, theta_eval) if row_based
-                     else grad_fn(theta_eval))
-            risk = risk_fn(row, theta) if row_based else risk_fn(theta)
+                    g = (grad_fn(row, theta_eval) if row_based
+                         else grad_fn(theta_eval))
+            with jax.named_scope("repro.mc.risk"):
+                risk = risk_fn(row, theta) if row_based else risk_fn(theta)
             if use_ec:
                 u = g + p["ec"] * e_res
                 sq = jnp.sum(u * u, axis=1)
@@ -284,61 +283,70 @@ def _mc_core_impl(params, betas, theta0, seeds, data, *, grad_fn, risk_fn,
                 # residual sees the MASKED transmission: a dropped node
                 # carries its whole update forward as error feedback
                 e_res = p["ec"] * (u - x_tx)
-            cum_e = cum_e + p["energy"] * jnp.sum(
-                x_tx.astype(jnp.float32) ** 2)
-            v = slot(x_tx, k, dr_all)
+            with jax.named_scope("repro.mc.energy"):
+                cum_e = cum_e + p["energy"] * jnp.sum(
+                    x_tx.astype(jnp.float32) ** 2)
+            with jax.named_scope("repro.mc.slot"):
+                v = slot(x_tx, k, dr_all)
             m = p["gamma"] * m + v
             theta = theta - beta * m
             carry = (theta, m, e_res, cum_e) if use_ec \
                 else (theta, m, cum_e)
             return carry, (risk, cum_e)
 
-        step_keys = jax.random.split(key, steps)
-        data_keys = None
-        if sgrad_fn is not None:
-            data_keys = jax.random.split(
-                jax.random.fold_in(key, _DATA_STREAM), steps)
-        draws_all = None
-        idx_all = None
-        if hoist:
-            # The universal RNG plan: every registered stream materializes
-            # as one batched (steps, ...) draw outside the scan, via each
-            # algorithm's hoist_draws twin. Streams replay the in-scan
-            # key-split chains exactly, so the plans are interchangeable.
-            ctx0 = make_ctx()
-            draws_all = {}
-            for a in algo_set:
-                hd = ALGO_REGISTRY[a].hoist_draws
-                if hd is not None:
-                    draws_all[a] = hd(step_keys, ctx0, n_max_, dim)
-            if not draws_all:
-                # algorithm registered without a hoist twin: nothing was
-                # hoisted — fall through to the legacy in-scan body
-                draws_all = None
-            if hoist_idx:
-                idx_all = jax.vmap(
-                    lambda dk: sample_idx_fn(row, dk, b_max))(data_keys)
         carry0 = (t0, jnp.zeros_like(t0), jnp.float32(0.0))
         if use_ec:
             carry0 = (t0, jnp.zeros_like(t0),
                       jnp.zeros((row["mask"].shape[0], t0.shape[0]),
                                 jnp.float32), jnp.float32(0.0))
-        part_u = None
-        if participation_on:
-            # the mask stream is hoisted under EVERY rng plan (one code
-            # path): a batched uniform over `split(fold_in(key, part), steps)`
-            # is stream-identical to per-step in-scan draws over the same
-            # keys, and the body stays pure linear algebra
-            part_keys = jax.random.split(
-                jax.random.fold_in(key, _PART_STREAM), steps)
-            part_u = jax.vmap(
-                lambda pk: jax.random.uniform(pk, (n_max_,), jnp.float32))(
-                    part_keys)
-        carry_fin, (risks, cum_e) = jax.lax.scan(
-            body, carry0,
-            (step_keys, data_keys, draws_all, idx_all, part_u))
+        with jax.named_scope("repro.mc.draws"):
+            step_keys = jax.random.split(key, steps)
+            data_keys = None
+            if sgrad_fn is not None:
+                data_keys = jax.random.split(
+                    jax.random.fold_in(key, _DATA_STREAM), steps)
+            draws_all = None
+            idx_all = None
+            if hoist:
+                # The universal RNG plan: every registered stream materializes
+                # as one batched (steps, ...) draw outside the scan, via each
+                # algorithm's hoist_draws twin. Streams replay the in-scan
+                # key-split chains exactly, so the plans are interchangeable.
+                ctx0 = make_ctx()
+                draws_all = {}
+                for a in algo_set:
+                    hd = ALGO_REGISTRY[a].hoist_draws
+                    if hd is not None:
+                        draws_all[a] = hd(step_keys, ctx0, n_max_, dim)
+                if not draws_all:
+                    # algorithm registered without a hoist twin: nothing was
+                    # hoisted — fall through to the legacy in-scan body
+                    draws_all = None
+                if hoist_idx:
+                    idx_all = jax.vmap(
+                        lambda dk: sample_idx_fn(row, dk, b_max))(data_keys)
+            part_u = None
+            if participation_on:
+                # the mask stream is hoisted under EVERY rng plan (one
+                # code path): a batched uniform over
+                # `split(fold_in(key, part), steps)` is stream-identical to
+                # per-step in-scan draws over the same keys, and the body
+                # stays pure linear algebra
+                part_keys = jax.random.split(
+                    jax.random.fold_in(key, _PART_STREAM), steps)
+                part_u = jax.vmap(lambda pk: jax.random.uniform(
+                    pk, (n_max_,), jnp.float32))(part_keys)
+        # the scan's own work (the state update, each step's slice of the
+        # hoisted draws, the stacked outputs) is `repro.mc.scan`; the
+        # body's scopes name the rest
+        with jax.named_scope("repro.mc.scan"):
+            carry_fin, (risks, cum_e) = jax.lax.scan(
+                body, carry0,
+                (step_keys, data_keys, draws_all, idx_all, part_u))
         theta_fin = carry_fin[0]
-        fin = risk_fn(row, theta_fin) if row_based else risk_fn(theta_fin)
+        with jax.named_scope("repro.mc.risk"):
+            fin = risk_fn(row, theta_fin) if row_based \
+                else risk_fn(theta_fin)
         risks = jnp.concatenate([risks, fin[None]])
         return risks, cum_e  # (steps+1,), (steps,)
 
@@ -495,20 +503,25 @@ def install_chunk_fault_hook(hook):
     return remove
 
 
-def _attempt_chunk(retry, off, stage, attempt_fn, reset_fn=None):
+def _attempt_chunk(retry, off, stage, attempt_fn, reset_fn=None,
+                   span=None):
     """Run one chunk with the plan's `RetryPolicy`: on an exception the
     accumulator state is rolled back (`reset_fn`), the policy's capped
     exponential backoff waits, and the chunk re-runs — replaying its
     exact counter-based streams, so a retried chunk is indistinguishable
     from a first-try one. `retry=None` (or an exhausted budget)
     re-raises: fail-fast is the legacy behavior and the checkpoint on
-    disk stays at the last completed chunk."""
+    disk stays at the last completed chunk. The attempt that succeeded
+    is recorded on the chunk's `span` as its `attempt` stat."""
     attempt = 1
     while True:
         try:
             for hook in list(_CHUNK_FAULT_HOOKS):
                 hook({"off": int(off), "attempt": attempt, "stage": stage})
-            return attempt_fn()
+            out = attempt_fn()
+            if span is not None:
+                span.set_metadata(attempt=attempt)
+            return out
         except Exception:
             if retry is None or attempt >= retry.max_attempts:
                 raise
@@ -582,9 +595,14 @@ def _workload_fingerprint(params, betas, theta0, seed_ints, data,
     return np.frombuffer(h.digest(), np.uint8)
 
 
+def _chunk_span(call: int, chunk: int):
+    return jax.profiler.TraceAnnotation("repro.run_mc.chunk", call=call,
+                                        chunk=chunk)
+
+
 def run_chunked(params, betas, theta0, seed_ints, data, *, seed_chunk,
                 keep_seed_curves, n_shards, row_shards=1, core_kwargs,
-                resume_dir=None, retry=None):
+                resume_dir=None, retry=None, call=0):
     """Drive the seed axis in blocks of `seed_chunk` through one compiled
     program (chunk seed ints are data). Returns the same
     (risks, cum_energy, mean, ci95) quadruple as the single-shot paths,
@@ -616,6 +634,10 @@ def run_chunked(params, betas, theta0, seed_ints, data, *, seed_chunk,
     exponential backoff; counter-based RNG replays its exact streams, so
     a sweep surviving k faults within budget is bit-identical to the
     fault-free run.
+
+    Each chunk, with its retries and its transfer to the host, is one
+    `repro.run_mc.chunk` span carrying `call` (the `run_mc` call's
+    number), `chunk` (its index) and `attempt`.
     """
     seeds = len(seed_ints)
     if seed_chunk <= 0:
@@ -642,9 +664,11 @@ def run_chunked(params, betas, theta0, seed_ints, data, *, seed_chunk,
                                 n_shards=n_shards, row_shards=row_shards,
                                 **core_kwargs)
 
-            r, ce = _attempt_chunk(retry, off, "curves", _run)
-            risks[:, off:off + seed_chunk] = np.asarray(r)
-            cum_e[:, off:off + seed_chunk] = np.asarray(ce)
+            with _chunk_span(call, off // seed_chunk) as span:
+                r, ce = _attempt_chunk(retry, off, "curves", _run,
+                                       span=span)
+                risks[:, off:off + seed_chunk] = np.asarray(r)
+                cum_e[:, off:off + seed_chunk] = np.asarray(ce)
         return (risks, cum_e) + host_seed_stats(risks)
     fp = _workload_fingerprint(params, betas, theta0, seed_ints, data,
                                seed_chunk, n_rows, n_shards, row_shards,
@@ -684,11 +708,6 @@ def run_chunked(params, betas, theta0, seed_ints, data, *, seed_chunk,
                 "restarting the sweep from the first chunk")
     for off in range(start, seeds, seed_chunk):
         blk = jnp.asarray(seed_ints[off:off + seed_chunk])
-        # the merge DONATES the accumulators: for retry, snapshot them to
-        # host first so a failed attempt can roll back (the f32 round-trip
-        # is value-preserving — bit-identity holds)
-        snap = (np.asarray(acc_mean), np.asarray(acc_m2)) \
-            if retry is not None else None
 
         def _merge(blk=blk, off=off):
             return _mc_moments_merge(
@@ -696,22 +715,29 @@ def run_chunked(params, betas, theta0, seed_ints, data, *, seed_chunk,
                 blk, data, n_shards=n_shards, row_shards=row_shards,
                 **core_kwargs)
 
-        def _reset(snap=snap):
-            nonlocal acc_mean, acc_m2
-            acc_mean = jnp.asarray(snap[0])
-            acc_m2 = jnp.asarray(snap[1])
+        with _chunk_span(call, off // seed_chunk) as span:
+            # the merge DONATES the accumulators: for retry, snapshot them
+            # to host first so a failed attempt can roll back (the f32
+            # round-trip is value-preserving — bit-identity holds)
+            snap = (np.asarray(acc_mean), np.asarray(acc_m2)) \
+                if retry is not None else None
 
-        acc_mean, acc_m2 = _attempt_chunk(
-            retry, off, "moments", _merge,
-            _reset if retry is not None else None)
-        if ckpt_path is not None:
-            # np.asarray copies to host BEFORE the next merge donates the
-            # accumulator buffers back to XLA
-            ckpt.save(ckpt_path, {
-                "fingerprint": fp,
-                "next_off": np.int64(off + seed_chunk),
-                "acc_mean": np.asarray(acc_mean),
-                "acc_m2": np.asarray(acc_m2)})
+            def _reset(snap=snap):
+                nonlocal acc_mean, acc_m2
+                acc_mean = jnp.asarray(snap[0])
+                acc_m2 = jnp.asarray(snap[1])
+
+            acc_mean, acc_m2 = _attempt_chunk(
+                retry, off, "moments", _merge,
+                _reset if retry is not None else None, span=span)
+            if ckpt_path is not None:
+                # np.asarray copies to host BEFORE the next merge donates
+                # the accumulator buffers back to XLA
+                ckpt.save(ckpt_path, {
+                    "fingerprint": fp,
+                    "next_off": np.int64(off + seed_chunk),
+                    "acc_mean": np.asarray(acc_mean),
+                    "acc_m2": np.asarray(acc_m2)})
     mean, ci95 = finalize_merged_stats(
         np.asarray(acc_mean), np.asarray(acc_m2), seeds)
     return None, None, mean, ci95

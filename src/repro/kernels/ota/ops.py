@@ -8,8 +8,9 @@ passes `interpret=True` (the CPU tests). Pads N and d to tile boundaries.
 whose edge-noise std depends on it) reuses one compiled program per
 (shape, impl) pair instead of recompiling per float value. Only `impl`,
 `interpret` and `out_dtype` remain static. `trace_count()` /
-`clear_cache()` mirror `repro.core.montecarlo`'s compile-counting surface
-so tests can assert the wrapper's compile behaviour.
+`clear_cache()` mirror the Monte Carlo engine's compile-counting surface
+(`repro.core.mc.trace_count` / `clear_cache`) so tests can assert the
+wrapper's compile behaviour.
 """
 from __future__ import annotations
 
@@ -18,27 +19,23 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.ota.kernel import ota_edge_aggregate_kernel
 from repro.kernels.ota.ref import ota_edge_aggregate_ref
 
-_TRACE_COUNT = 0
+_TRACES = obs.Counter()  # wrapper traces (== XLA compiles)
 
 
 def trace_count(reset: bool = False) -> int:
     """Times the jitted wrapper body has been traced (== XLA compiles)
     since import or the last reset; `clear_cache()` also zeroes it."""
-    global _TRACE_COUNT
-    count = _TRACE_COUNT
-    if reset:
-        _TRACE_COUNT = 0
-    return count
+    return _TRACES.value(reset)
 
 
 def clear_cache() -> bool:
     """Drop the wrapper's compiled cache and reset the trace counter.
     Returns False on JAX versions without jit clear_cache support."""
-    global _TRACE_COUNT
-    _TRACE_COUNT = 0
+    _TRACES.value(reset=True)
     if hasattr(_ota_edge_aggregate, "clear_cache"):
         _ota_edge_aggregate.clear_cache()
         return True
@@ -52,8 +49,7 @@ def _on_tpu() -> bool:
 @functools.partial(jax.jit, static_argnames=("impl", "interpret", "out_dtype"))
 def _ota_edge_aggregate(grads, gains, noise, noise_scale, *, impl, interpret,
                         out_dtype):
-    global _TRACE_COUNT
-    _TRACE_COUNT += 1  # python side effect: runs once per trace/compile
+    _TRACES.add()  # python side effect: runs once per trace/compile
     if impl == "ref":
         return ota_edge_aggregate_ref(grads, gains, noise,
                                       noise_scale=noise_scale,

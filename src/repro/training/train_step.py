@@ -213,24 +213,29 @@ def build_train_step(model: Model, tcfg: TrainConfig, opt: Optimizer
         bsz = batch["tokens"].shape[0]
 
         if tcfg.aggregator == "gbma" and gcfg.enabled:
-            weights = node_weights(k_h, gcfg, bsz)
+            with jax.named_scope("repro.gbma.weights"):
+                weights = node_weights(k_h, gcfg, bsz)
         else:
             weights = jnp.ones((bsz,), jnp.float32)
 
-        if tcfg.microbatches > 1:
-            clean_loss, grads = _accumulated_grads(
-                vg, params, batch, weights, tcfg.microbatches, model.cfg.fsdp)
-        else:
-            clean_loss, grads = vg(params, batch, weights)
-            grads = _constrain_like_params(grads, model.cfg.fsdp)
+        with jax.named_scope("repro.train.loss_grad"):
+            if tcfg.microbatches > 1:
+                clean_loss, grads = _accumulated_grads(
+                    vg, params, batch, weights, tcfg.microbatches,
+                    model.cfg.fsdp)
+            else:
+                clean_loss, grads = vg(params, batch, weights)
+                grads = _constrain_like_params(grads, model.cfg.fsdp)
 
-        if tcfg.aggregator == "gbma" and gcfg.enabled:
-            grads = perturb_gradients(grads, k_w, gcfg)
-        elif tcfg.aggregator == "fdm":
-            grads = _fdm_noise(grads, k_w, gcfg)
+        with jax.named_scope("repro.gbma.noise"):
+            if tcfg.aggregator == "gbma" and gcfg.enabled:
+                grads = perturb_gradients(grads, k_w, gcfg)
+            elif tcfg.aggregator == "fdm":
+                grads = _fdm_noise(grads, k_w, gcfg)
 
-        grads, metrics = _clip_and_metrics(grads, tcfg)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("repro.train.optimizer"):
+            grads, metrics = _clip_and_metrics(grads, tcfg)
+            params, opt_state = opt.update(grads, opt_state, params)
         metrics["loss"] = clean_loss
         metrics["noise_std"] = (edge_noise_std(gcfg.channel, gcfg.n_nodes)
                                 if tcfg.aggregator == "gbma" else 0.0)
@@ -279,16 +284,19 @@ def _build_transport_step(model: Model, tcfg: TrainConfig, opt: Optimizer,
 
         eval_params = transport.lookahead_params(algo, params, agg_state, tp) \
             if spec.nesterov else params
-        clean_loss, node_g = grads_fn(eval_params, batch)
-        node_g = jax.vmap(
-            lambda g: _constrain_like_params(g, model.cfg.fsdp))(node_g)
+        with jax.named_scope("repro.train.loss_grad"):
+            clean_loss, node_g = grads_fn(eval_params, batch)
+            node_g = jax.vmap(
+                lambda g: _constrain_like_params(g, model.cfg.fsdp))(node_g)
 
-        update, agg_state, aux = transport.aggregate(
-            algo, node_g, slot_key, tp, agg_state)
-        update = _constrain_like_params(update, model.cfg.fsdp)
+        with jax.named_scope("repro.transport.aggregate"):
+            update, agg_state, aux = transport.aggregate(
+                algo, node_g, slot_key, tp, agg_state)
+            update = _constrain_like_params(update, model.cfg.fsdp)
 
-        update, metrics = _clip_and_metrics(update, tcfg)
-        params, opt_state = opt.update(update, opt_state, params)
+        with jax.named_scope("repro.train.optimizer"):
+            update, metrics = _clip_and_metrics(update, tcfg)
+            params, opt_state = opt.update(update, opt_state, params)
         if stateful:
             opt_state = (opt_state, agg_state)
         metrics["loss"] = clean_loss
