@@ -245,10 +245,11 @@ def _ota_slot(g: Array, key: Array, ctx: SlotCtx) -> Array:
     plan supplied them (`ctx.draws`), k_w the (d,) edge noise —
     split-for-split identical to `gbma.ota_aggregate`. With
     `ctx.ota_impl != 'inline'` the superposition + noise-add routes through
-    the tiled `repro.kernels.ota.ota_edge_aggregate` kernel: compiled for
-    the TPU ('pallas'), run by the Pallas interpreter ('interpret'), or the
-    jnp oracle ('ref'); the traced noise std folds into the noise operand
-    so the kernel's static `noise_scale` stays 1.
+    `repro.kernels.ota.ota_edge_aggregate`: compiled for the TPU
+    ('pallas'), run by the Pallas interpreter ('interpret'), or the jnp
+    oracle ('ref'); under the engine's vmaps every trajectory's slot is one
+    batched kernel. The traced noise std folds into the noise operand so
+    the kernel's static `noise_scale` stays 1.
     """
     p = ctx.p
     if ctx.draws is not None:

@@ -1,19 +1,28 @@
-"""Pallas TPU kernel for the fused OTA-MAC edge aggregation (paper Eq. 8).
+"""Pallas TPU kernels for the fused OTA-MAC edge aggregation (paper Eq. 8).
 
-Computes  v = (1/N) * sum_n h[n] * g[n, :] + noise_scale * w  over a tile grid,
-fusing the per-node gain scaling, the MAC superposition (the reduction), the
-1/N matched-filter normalization and the edge-noise add. The (N, d) matrix of
-*scaled* gradients is never materialized in HBM: node blocks stream through
-VMEM and accumulate into a d-tile resident accumulator.
+Computes  v = (1/N) * sum_n h[n] * g[n, :] + noise_scale * w, fusing the
+per-node gain scaling, the MAC superposition (the reduction), the 1/N
+matched-filter normalization and the edge-noise add. The (N, d) matrix of
+*scaled* gradients is never materialized in HBM. Two kernels:
 
-TPU adaptation notes (vs the radio physical layer / a GPU port):
-  * the "superposition" is a VMEM-resident accumulation over node blocks —
-    the reduction dimension (nodes) is tiled innermost so each d-tile of the
-    output is produced once (one HBM write per output tile);
-  * tiles are (NODE_BLK, LANE_BLK) with LANE_BLK a multiple of 128 to align
-    with the VPU lane width; the gain vector block is broadcast across lanes;
-  * accumulation is fp32 regardless of input dtype (bf16 gradients are
-    upcast on load), matching the MXU/VPU-native mixed-precision idiom.
+  * `ota_edge_aggregate_kernel`, one aggregation of an (N, d) stack over a
+    tile grid: node blocks stream through VMEM and accumulate into a
+    d-tile resident accumulator (the reduction dimension is tiled
+    innermost, so each d-tile of the output is written once). Tiles are
+    (NODE_BLK, LANE_BLK) with LANE_BLK a multiple of 128 (the VPU lane
+    width); the caller pads N and d to them and passes the gains as an
+    (N, 1) column broadcast across lanes.
+  * `ota_edge_aggregate_batched`, B independent aggregations of a
+    (B, N, d) stack, as a vmapped caller (the Monte Carlo engine's
+    trajectories) produces them: the grid runs over blocks of `tb`
+    trajectories, each block holds every node and every coordinate at
+    their true extent (no padding, no node-block reduction axis), and the
+    gains arrive lane-dense as (tb, N) and move to sublanes in VMEM. A
+    partial last block is fine: its rows are independent and the rows
+    past B are never written back.
+
+Accumulation is fp32 regardless of input dtype (bf16 gradients are upcast
+on load), matching the MXU/VPU-native mixed-precision idiom.
 """
 from __future__ import annotations
 
@@ -106,3 +115,50 @@ def ota_edge_aggregate_kernel(
         interpret=interpret,
     )(grads, gains.reshape(n, 1), noise.reshape(1, d))
     return out.reshape(d)
+
+
+def _ota_batched_kernel(g_ref, h_ref, w_ref, o_ref, *, n_nodes: int):
+    """One block of trajectories: g (tb, N, d), h (tb, N), w and o (tb, d)."""
+    g = g_ref[...].astype(jnp.float32)
+    h = h_ref[...].astype(jnp.float32)[:, :, None]  # gains onto sublanes
+    v = jnp.sum(h * g, axis=1) / n_nodes
+    o_ref[...] = (v + w_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("tb", "vmem_limit", "interpret", "out_dtype"))
+def ota_edge_aggregate_batched(
+    grads: jax.Array,  # (B, N, d)
+    gains: jax.Array,  # (B, N)
+    noise: jax.Array,  # (B, d) edge noise, already scaled
+    *,
+    tb: int,
+    vmem_limit: int,
+    interpret: bool = False,
+    out_dtype=None,
+) -> jax.Array:
+    """v[b] = (1/N) sum_n gains[b, n] grads[b, n, :] + noise[b] for every
+    b, in one `pallas_call` whose grid runs over ceil(B / tb) blocks of
+    `tb` trajectories (a multiple of 8, or B itself). `vmem_limit` is the
+    scoped VMEM the kernel may use. `out_dtype` (default: grads.dtype) is
+    the emission dtype of the f32 sum."""
+    b, n, d = grads.shape
+    if out_dtype is None:
+        out_dtype = grads.dtype
+    if tb != b and tb % 8:
+        raise ValueError(f"tb={tb} must be a multiple of 8 or B={b}")
+    return pl.pallas_call(
+        functools.partial(_ota_batched_kernel, n_nodes=n),
+        grid=(pl.cdiv(b, tb),),
+        in_specs=[
+            pl.BlockSpec((tb, n, d), lambda i: (i, 0, 0)),  # grads
+            pl.BlockSpec((tb, n), lambda i: (i, 0)),  # gains
+            pl.BlockSpec((tb, d), lambda i: (i, 0)),  # noise
+        ],
+        out_specs=pl.BlockSpec((tb, d), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, d), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name="ota_edge_aggregate_batched",
+    )(grads, gains, noise)

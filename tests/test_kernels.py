@@ -85,6 +85,115 @@ def test_ota_noise_scale_is_traced_one_compile():
     assert ota_ops.trace_count() == 1
 
 
+def _ota_stack(shape, n, d, dtype, seed):
+    """Gradients (*shape, n, d), gains (*shape, n), noise (*shape, d)."""
+    kg, kh, kw = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(kg, (*shape, n, d), dtype=dtype),
+            jax.random.uniform(kh, (*shape, n)),
+            jax.random.normal(kw, (*shape, d)))
+
+
+def _ota_pallas(g, h, w):
+    return ota_edge_aggregate(g, h, w, noise_scale=0.37, impl="pallas",
+                              interpret=True, out_dtype=jnp.float32)
+
+
+def _ota_oracle(g, h, w):
+    return ota_edge_aggregate_ref(g, h, w, noise_scale=0.37,
+                                  out_dtype=jnp.float32)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and its sub-jaxprs, except the insides of
+    Pallas kernels (what they do happens in VMEM, not on the operands)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+# (a, b): the outer and inner vmap sizes; B = a * b is not a multiple of
+# the batched kernel's block for (500, 90), (4096, 24) and (130, 129)
+@pytest.mark.parametrize("n,d,a,b", [(500, 90, 3, 13), (4096, 24, 2, 5),
+                                     (7, 5, 3, 4), (130, 129, 3, 20)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ota_batched_route_matches_ref(n, d, a, b, dtype):
+    """vmap(vmap(.)) of the OTA aggregation runs the batched kernel over
+    the whole (a*b, N, d) stack (a partial last block included) and
+    matches the oracle; bf16 gradients emit an f32 update."""
+    from repro import obs
+    from repro.kernels.ota import ops as ota_ops
+
+    g, h, w = _ota_stack((a, b), n, d, dtype, seed=n * d + a)
+    ota_ops.clear_cache()  # a fresh trace, so the vmap rule runs
+    before = obs.counters()
+    out = jax.vmap(jax.vmap(_ota_pallas))(g, h, w)
+    after = obs.counters()
+    assert (after["repro.ota.batched_routes"]
+            > before["repro.ota.batched_routes"])
+    assert after["repro.ota.tiled_routes"] == before["repro.ota.tiled_routes"]
+    if (n, d) != (7, 5):  # (7, 5): the whole stack is one block
+        tb = ota_ops._batch_block(a * b, n, d, dtype)
+        assert (a * b) % tb, "the batch should leave a partial last block"
+    assert out.dtype == jnp.float32
+    ref = jax.vmap(jax.vmap(_ota_oracle))(g, h, w)
+    np.testing.assert_allclose(np.array(out), np.array(ref), atol=2e-6,
+                               rtol=1e-5)
+
+
+def test_ota_shared_gradients_keep_the_tiled_route():
+    """A vmap that shares one gradient stack across its batch (the
+    multi-antenna MRC slot: gains and noise per antenna) keeps today's
+    vmapped 2-D kernel: the gradients are never broadcast."""
+    from repro import obs
+    from repro.kernels.ota import ops as ota_ops
+
+    g, _, _ = _ota_stack((), 130, 90, jnp.float32, seed=5)
+    _, h, w = _ota_stack((4,), 130, 90, jnp.float32, seed=6)
+    ota_ops.clear_cache()
+    before = obs.counters()
+    fn = jax.vmap(_ota_pallas, in_axes=(None, 0, 0))
+    out = fn(g, h, w)
+    after = obs.counters()
+    assert after["repro.ota.tiled_routes"] > before["repro.ota.tiled_routes"]
+    assert (after["repro.ota.batched_routes"]
+            == before["repro.ota.batched_routes"])
+    calls = [e for e in _eqns(jax.make_jaxpr(fn)(g, h, w).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].grid[0] == 4  # the antenna axis
+    ref = jax.vmap(_ota_oracle, in_axes=(None, 0, 0))(g, h, w)
+    np.testing.assert_allclose(np.array(out), np.array(ref), atol=2e-6,
+                               rtol=1e-5)
+
+
+def test_ota_batched_route_jaxpr():
+    """vmap(vmap(.)) at (3, 4) x (500, 90) is ONE pallas_call whose grid
+    has at most ceil(B / tb) steps, and nothing pads the gradients or lays
+    the gains out as (..., N, 1). The per-trajectory route showed
+    grid=(3, 4, 1, 4) and a reshape to (3, 4, 512, 1)."""
+    from repro.kernels.ota import ops as ota_ops
+
+    a, b, n, d = 3, 4, 500, 90
+    g, h, w = _ota_stack((a, b), n, d, jnp.float32, seed=7)
+    jaxpr = jax.make_jaxpr(jax.vmap(jax.vmap(_ota_pallas)))(g, h, w).jaxpr
+    eqns = list(_eqns(jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    grid = calls[0].params["grid_mapping"].grid
+    tb = ota_ops._batch_block(a * b, n, d, g.dtype)
+    assert int(np.prod(grid)) <= -(-(a * b) // tb)
+    assert not [e for e in eqns if e.primitive.name == "pad"]
+    relayouts = [e for e in eqns if e.primitive.name == "reshape"
+                 and tuple(e.params["new_sizes"][-1:]) == (1,)]
+    assert not relayouts
+
+
 # ---------------------------------------------------------- attention kernel
 @pytest.mark.parametrize("b,hq,hkv,s,d,kw", [
     (2, 4, 4, 256, 64, {}),

@@ -374,16 +374,26 @@ def test_ota_impl_ref_parity(prob, mc):
                                atol=1e-9)
 
 
-def test_ota_impl_pallas_parity_interpret(prob, mc):
+@pytest.mark.parametrize("rows,seeds", [(1, 1), (2, 3)])
+def test_ota_impl_pallas_parity_interpret(prob, mc, rows, seeds):
     """The Pallas kernel path, run in the Pallas interpreter (asked for
-    explicitly: 'pallas' itself is TPU-only), matches inline. Short
-    horizon: interpret mode is slow."""
-    ch = _ch()
-    beta = stepsize_theorem1(prob.pc, ch, N, safety=0.8)
+    explicitly: 'pallas' itself is TPU-only), matches inline. The engine's
+    vmap(rows) x vmap(seeds) reaches the batched kernel with T = rows x
+    seeds trajectories. Short horizon: interpret mode is slow."""
+    from repro import obs
+    from repro.kernels.ota import ops as ota_ops
+
+    chs = [_ch(), _ch(noise_std=1.5)][:rows]
+    betas = [stepsize_theorem1(prob.pc, ch, N, safety=0.8) for ch in chs]
     steps = 5
-    r_inline = run_mc(mc, [ch], "gbma", [beta], steps, 1)
-    r_pallas = run_mc(mc, [ch], "gbma", [beta], steps, 1,
+    r_inline = run_mc(mc, chs, "gbma", betas, steps, seeds)
+    # fresh traces, so the vmap rules run and count the route
+    mc_mod.clear_cache()
+    ota_ops.clear_cache()
+    before = obs.counters()["repro.ota.batched_routes"]
+    r_pallas = run_mc(mc, chs, "gbma", betas, steps, seeds,
                       ota_impl="interpret")
+    assert obs.counters()["repro.ota.batched_routes"] > before
     np.testing.assert_allclose(r_pallas.risks, r_inline.risks, rtol=1e-5,
                                atol=1e-9)
 

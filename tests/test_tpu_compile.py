@@ -7,7 +7,9 @@ chip. Shapes are the real ones:
 
   * the OTA kernel vmapped over seeds inside a scan, as the Monte Carlo
     engine calls it on the `large_chunked` workload (32 seeds, N=4096,
-    d=24) and at the paper's Fig. 3 point (N=500, d=90);
+    d=24) and at the paper's Fig. 3 point (N=500, d=90), and under the
+    engine's vmap(rows) x vmap(seeds) at Fig. 3(b)'s 3 x 256 trajectories:
+    the batched kernel, one per slot;
   * the OTA kernel at a transport tile of repro-100m training (8 nodes,
     262144 columns, bf16 in, f32 out);
   * the attention kernel at repro-100m widths (batch 8 x 10 heads, seq 256,
@@ -79,6 +81,32 @@ def test_ota_kernel_as_the_engine_calls_it(one_chip, seeds, n, d):
         _spec((steps, seeds, n), jnp.float32, one_chip),
         _spec((steps, seeds, d), jnp.float32, one_chip)).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("batch,n,d", [((3, 256), 500, 90),
+                                       ((32,), 4096, 24)])
+def test_ota_batched_kernel_as_the_engine_calls_it(one_chip, batch, n, d):
+    """vmap(rows) x vmap(seeds) (or vmap(seeds) alone) of the slot inside
+    a scan: one batched kernel per slot, whose blocks and VMEM Mosaic
+    accepts, and no pad of the gradients."""
+    def trajectory(g, hs, ws):
+        def body(theta, xs):
+            v = _ota(g * theta, xs[0], xs[1])
+            return theta - 0.01 * jnp.mean(v), None
+        return jax.lax.scan(body, jnp.float32(1.0), (hs, ws))[0]
+
+    fn = trajectory
+    for _ in batch:
+        fn = jax.vmap(fn)
+    steps = 3
+    compiled = jax.jit(fn).lower(
+        _spec((*batch, n, d), jnp.float32, one_chip),
+        _spec((*batch, steps, n), jnp.float32, one_chip),
+        _spec((*batch, steps, d), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ota_edge_aggregate_batched" in text
+    assert " pad(" not in text
 
 
 def test_ota_kernel_at_the_transport_tile(one_chip):
